@@ -9,24 +9,18 @@ Two sections, both emitted through run.py's schema-validated record path:
   full (n, p) matrix never exists host-side, so peak memory stays bounded
   by the chunk size while full-batch peaks at the materialized matrix.
   The largest n runs stream-only (the point of the streaming path).
-* ``scale/scoring/shard=…`` — 1-vs-2-shard ``ScoringEngine.score``
-  rows/sec at serving bucket sizes, run in a subprocess with two forced
-  host devices (the harness keeps the parent at 1).
-  ``scale/scoring/shard_speedup/...`` carries the headline ratio
-  (acceptance: >= 1.5x at the largest bucket).
+* ``scale/scoring/shard=…`` — 1-shard vs one-shard-per-local-device
+  ``ScoringEngine.score`` rows/sec at serving bucket sizes, in this
+  process. ``scale/scoring/shard_speedup/...`` carries the ratio when
+  more than one device is visible.
 
 Rows are (name, us_per_call, derived[, value]) as in bench_serving.py.
 """
-import json
-import os
-import subprocess
 import sys
 import time
 import tracemalloc
 
 import numpy as np
-
-ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 FIT_P = 32
 STREAM_CHUNK = 32768
@@ -127,84 +121,59 @@ def _fit_rows(n_list, stream_only, iters, lam2=0.01):
     return rows
 
 
-# -- sharded scoring (subprocess: parent process keeps 1 device) ------------
+# -- sharded scoring (in-process: a child could not reach a held chip) ------
 
-_SCORING_SCRIPT = r"""
-import json, os, sys, time
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=2"
-import numpy as np
-from repro.data.synthetic import SyntheticSpec, make_correlated_survival
-from repro.serving import ScoringEngine, fit_survival_model
+def _scoring_rows(buckets, reps, rounds=3):
+    """1-shard vs one-shard-per-local-device ``ScoringEngine.score``.
 
-buckets = json.loads(sys.argv[1])
-grid = int(sys.argv[2])
-reps = int(sys.argv[3])
-p = 32
+    Runs in this process over ``jax.local_device_count()`` devices (on the
+    CPU, start the process with
+    ``XLA_FLAGS=--xla_force_host_platform_device_count=2``); with a single
+    device only the 1-shard rows are emitted. Sharded results must equal
+    unsharded ones exactly before any timing is reported."""
+    import jax
 
-x, t, delta, beta_star = make_correlated_survival(
-    SyntheticSpec(n=2000, p=p, k=4, rho=0.5, seed=0, censor_scale=3.0))
-model = fit_survival_model(x, t, delta, beta_star, grid_size=grid)
-rng = np.random.default_rng(1)
-out = {}
-ROUNDS = 3
-for b in buckets:
-    feats = rng.standard_normal((b, p)).astype(np.float32)
-    # use_kernel=False: the jnp path is the production path on CPU
-    # (Pallas only interprets here)
-    engines = {s: ScoringEngine(model, use_sparse=False, use_kernel=False,
-                                shard=None if s == 1 else s)
-               for s in (1, 2)}
-    for eng in engines.values():
-        eng.score(feats); eng.score(feats)     # warm the bucket jit
-    # sustained mean over `reps` calls is the serving throughput metric;
-    # alternating rounds + min-of-round-means damp host noise on a
-    # shared box (both arms sample the same interference)
-    best = {1: float("inf"), 2: float("inf")}
-    for _ in range(ROUNDS):
-        for shard, eng in engines.items():
-            t0 = time.perf_counter()
-            for _ in range(reps):
-                r, m = eng.score(feats)
-            best[shard] = min(best[shard],
-                              (time.perf_counter() - t0) / reps)
-    for shard in (1, 2):
-        out[f"{shard}/{b}"] = best[shard]
-    # parity while we're here: sharded must equal unsharded bit-for-bit
-    e1 = ScoringEngine(model, use_sparse=False)
-    e2 = ScoringEngine(model, use_sparse=False, shard=2)
-    q = feats[: min(1024, b)]
-    r1, m1 = e1.score(q); r2, m2 = e2.score(q)
-    assert np.array_equal(r1, r2) and np.array_equal(m1, m2)
-print("RESULT " + json.dumps(out))
-"""
+    from repro.data.synthetic import SyntheticSpec, make_correlated_survival
+    from repro.serving import ScoringEngine, fit_survival_model
 
-
-def _scoring_rows(buckets, reps):
-    env = dict(os.environ)
-    env["PYTHONPATH"] = (os.path.join(ROOT, "src")
-                         + os.pathsep + env.get("PYTHONPATH", ""))
-    env.pop("XLA_FLAGS", None)
-    proc = subprocess.run(
-        [sys.executable, "-c", _SCORING_SCRIPT, json.dumps(list(buckets)),
-         str(SCORING_GRID), str(reps)],
-        env=env, capture_output=True, text=True, timeout=1800)
-    line = next((ln for ln in proc.stdout.splitlines()
-                 if ln.startswith("RESULT ")), None)
-    if line is None:
-        raise RuntimeError("scoring subprocess failed:\n"
-                           + proc.stdout + "\n---\n" + proc.stderr)
-    timings = json.loads(line[len("RESULT "):])
+    p = 32
+    x, t, delta, beta_star = make_correlated_survival(
+        SyntheticSpec(n=2000, p=p, k=4, rho=0.5, seed=0, censor_scale=3.0))
+    model = fit_survival_model(x, t, delta, beta_star,
+                               grid_size=SCORING_GRID)
+    shards = sorted({1, jax.local_device_count()})
+    on_cpu = jax.default_backend() == "cpu"
+    rng = np.random.default_rng(1)
     rows = []
     for b in buckets:
-        for shard in (1, 2):
-            dt = timings[f"{shard}/{b}"]
-            rps = b / dt
-            rows.append((f"scale/scoring/shard={shard}/b={b}", dt * 1e6,
+        feats = rng.standard_normal((b, p)).astype(np.float32)
+        # on the CPU the jnp path is the production path (Pallas only
+        # interprets there)
+        engines = {s: ScoringEngine(model, use_sparse=False,
+                                    use_kernel=not on_cpu,
+                                    shard=None if s == 1 else s)
+                   for s in shards}
+        ref = engines[1].score(feats)
+        for eng in engines.values():
+            out = eng.score(feats)                 # warm the bucket jit
+            assert all(np.array_equal(a, r) for a, r in zip(out, ref))
+        # sustained mean over `reps` calls is the serving throughput
+        # metric; alternating rounds + min-of-round-means damp host noise
+        best = {s: float("inf") for s in shards}
+        for _ in range(rounds):
+            for s, eng in engines.items():
+                t0 = time.perf_counter()
+                for _ in range(reps):
+                    eng.score(feats)
+                best[s] = min(best[s], (time.perf_counter() - t0) / reps)
+        for s in shards:
+            rps = b / best[s]
+            rows.append((f"scale/scoring/shard={s}/b={b}", best[s] * 1e6,
                          f"rows_per_s={rps:.0f} g={SCORING_GRID}", rps))
-        ratio = timings[f"1/{b}"] / timings[f"2/{b}"]
-        rows.append((f"scale/scoring/shard_speedup/b={b}", 0.0,
-                     f"x{ratio:.2f} (accept >= 1.5x at largest bucket)",
-                     ratio))
+        if len(shards) > 1:
+            ratio = best[1] / best[shards[-1]]
+            rows.append((f"scale/scoring/shard_speedup/b={b}", 0.0,
+                         f"x{ratio:.2f} at {shards[-1]} shards", ratio))
     return rows
 
 

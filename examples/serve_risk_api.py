@@ -23,7 +23,7 @@ import tempfile
 
 from repro.launch import runtime
 
-runtime.apply()   # env/XLA/dtype policy before jax initializes
+runtime.apply()   # env/XLA/compile-cache policy before jax initializes
 
 import numpy as np
 

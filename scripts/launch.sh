@@ -27,9 +27,7 @@ fi
 
 export TCMALLOC_LARGE_ALLOC_REPORT_THRESHOLD="${TCMALLOC_LARGE_ALLOC_REPORT_THRESHOLD:-60000000000}"
 export TF_CPP_MIN_LOG_LEVEL="${TF_CPP_MIN_LOG_LEVEL:-4}"      # no TF/XLA chatter
-export JAX_DEFAULT_DTYPE_BITS="${JAX_DEFAULT_DTYPE_BITS:-32}" # f32 dtype policy
 export XLA_FLAGS="${XLA_FLAGS:-}"                             # deployment flags slot
-export REPRO_TUNE_CACHE="${REPRO_TUNE_CACHE:-$ROOT/benchmarks/tuned_blocks.json}"
 export PYTHONPATH="$ROOT/src${PYTHONPATH:+:$PYTHONPATH}"
 
 exec /usr/bin/env python "$@"

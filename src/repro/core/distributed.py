@@ -30,7 +30,6 @@ import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from . import cox, surrogate
-from ..launch.mesh import shard_map_compat
 
 Array = jax.Array
 
@@ -70,8 +69,9 @@ def shard_revcumsum(x: Array, mesh, axis: str = "data") -> Array:
         return loc + right
 
     n = x.shape[0]
-    out = shard_map_compat(local, mesh=mesh, in_specs=P(axis),
-                           out_specs=P(axis))(_pad0(x, _axis_size(mesh, axis)))
+    out = jax.shard_map(local, mesh=mesh, in_specs=P(axis),
+                        out_specs=P(axis), check_vma=False)(
+        _pad0(x, _axis_size(mesh, axis)))
     return out[:n]
 
 
@@ -99,10 +99,11 @@ def _risk_stats_local(n_sh: int):
 
 
 def _risk_stats_padded(eta_p: Array, delta_p: Array, mask: Array, mesh):
-    return shard_map_compat(
+    return jax.shard_map(
         _risk_stats_local(_axis_size(mesh)), mesh=mesh,
         in_specs=(P("data"), P("data"), P("data")),
-        out_specs=(P("data"), P("data"), P("data")))(eta_p, delta_p, mask)
+        out_specs=(P("data"), P("data"), P("data")),
+        check_vma=False)(eta_p, delta_p, mask)
 
 
 def sharded_risk_stats(data: cox.CoxData, eta: Array, mesh):
@@ -154,8 +155,8 @@ def shard_revcumsum_2d(x: Array, mesh) -> Array:
         return loc + right[None, :]
 
     n = x.shape[0]
-    out = shard_map_compat(local, mesh=mesh, in_specs=P("data", "model"),
-                           out_specs=P("data", "model"))(
+    out = jax.shard_map(local, mesh=mesh, in_specs=P("data", "model"),
+                        out_specs=P("data", "model"), check_vma=False)(
         _pad0(x, _axis_size(mesh)))
     return out[:n]
 

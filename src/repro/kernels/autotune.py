@@ -11,10 +11,9 @@ bucket is untuned, so production paths never pay a timing cost. Winners
 are also registered into the roofline registry (``analysis/roofline.py``)
 so the report's tuned-blocks table shows tuned vs default.
 
-Cache location: ``$REPRO_TUNE_CACHE`` when set, else
-``~/.cache/repro/tuned_blocks.json``. ``benchmarks/run.py`` points the env
-var at ``benchmarks/tuned_blocks.json`` so the winners are committed
-alongside the ``BENCH_*.json`` trajectory artifacts.
+Cache location: ``$REPRO_TUNE_CACHE`` when set, else the committed
+``benchmarks/tuned_blocks.json`` of this checkout, so kernel blocks never
+come from a file outside the repository.
 """
 from __future__ import annotations
 
@@ -36,6 +35,9 @@ from .revcumsum import revcumsum
 from .survival_curves import survival_curves, survival_curves_stratified
 
 CACHE_ENV = "REPRO_TUNE_CACHE"
+DEFAULT_CACHE = os.path.join(
+    os.path.dirname(os.path.abspath(__file__)), "..", "..", "..",
+    "benchmarks", "tuned_blocks.json")
 CACHE_VERSION = 1
 
 # the static fallbacks — identical to the historical hard-coded blocks, so
@@ -74,7 +76,7 @@ BLOCK_AXES: Dict[str, Dict[str, str]] = {
 # size) and all TPU-tileable (multiples of the (8, 128) f32 tile)
 CANDIDATES: Dict[str, List[Dict[str, int]]] = {
     "revcumsum": [{"block_n": b} for b in (256, 512, 1024, 2048)],
-    "cox_coord": [{"block": b} for b in (512, 1024, 2048, 4096)],
+    "cox_coord": [{"block": b} for b in (1024, 2048, 4096, 8192)],
     "cox_batch": [
         {"block_n": 512, "block_p": 256},
         {"block_n": 1024, "block_p": 256},
@@ -131,8 +133,7 @@ def bucket_key(kernel: str, shape: Dict[str, int],
 # -- JSON cache -------------------------------------------------------------
 
 def cache_path() -> str:
-    return os.environ.get(CACHE_ENV) or os.path.join(
-        os.path.expanduser("~"), ".cache", "repro", "tuned_blocks.json")
+    return os.environ.get(CACHE_ENV) or os.path.normpath(DEFAULT_CACHE)
 
 _LOADED: Dict[str, Dict[str, dict]] = {}   # path -> entries (lazy, per file)
 

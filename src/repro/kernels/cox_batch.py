@@ -44,6 +44,7 @@ def _kernel(x_ref, r_ref, wa_ref, w_ref, d_ref, inv_s0_ref,
     def colsum(vec, mat):  # (bn,1)^T @ (bn,bp) -> (1,bp) on the MXU
         return jax.lax.dot_general(
             vec, mat, (((0,), (0,)), ((), ())),
+            precision=jax.lax.Precision.HIGHEST,
             preferred_element_type=jnp.float32)
 
     g_ref[...] += colsum(r, x)
@@ -52,6 +53,7 @@ def _kernel(x_ref, r_ref, wa_ref, w_ref, d_ref, inv_s0_ref,
     bn = x.shape[0]
     s1 = jax.lax.dot_general(
         _suffix_tri(bn), w * x, (((1,), (0,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST,
         preferred_element_type=jnp.float32) + carry_ref[...]
     m = s1 * inv_s0                            # (bn, bp)
     h_ref[...] += -colsum(d, m * m)

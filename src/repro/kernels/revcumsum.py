@@ -40,6 +40,7 @@ def _revcumsum_kernel(x_ref, o_ref, carry_ref):
     u = _suffix_tri(x.shape[0])
     suff = jax.lax.dot_general(
         u, x, (((1,), (0,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST,
         preferred_element_type=jnp.float32)
     o_ref[...] = (suff + carry_ref[...]).astype(o_ref.dtype)
     carry_ref[...] = carry_ref[...] + jnp.sum(x, axis=0, keepdims=True)

@@ -28,6 +28,7 @@ def _curves_kernel(eta_ref, h0_ref, o_ref):
     risk = jnp.exp(eta)
     prod = jax.lax.dot_general(
         risk, h0, (((1,), (0,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST,
         preferred_element_type=jnp.float32)
     o_ref[...] = jnp.exp(-prod).astype(o_ref.dtype)
 
@@ -95,24 +96,29 @@ def _survival_curves_strat_jit(eta: jax.Array, h0: jax.Array,
     pad_g = gb * block_g - g
     h0p = jnp.pad(h0, ((0, 0), (0, pad_g))) if pad_g else h0
 
+    # rows live on a leading squeezed axis, so every block's last two
+    # dims are (1, 1) or (1, block_g) of a (1, .) trailing panel: whole
+    # array dims or 128-lane multiples, as the TPU tiling requires
     out = pl.pallas_call(
         _curves_strat_kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=(b, gb),
             in_specs=[
-                pl.BlockSpec((1, 1), lambda i, j, s: (i, 0)),
+                pl.BlockSpec((None, 1, 1), lambda i, j, s: (i, 0, 0)),
                 # the prefetched strata vector drives which baseline row
                 # is DMA'd for grid step i — the gather never hits VMEM
                 # as a full (b, g) materialized panel
-                pl.BlockSpec((1, block_g), lambda i, j, s: (s[i], j)),
+                pl.BlockSpec((None, 1, block_g),
+                             lambda i, j, s: (s[i], 0, j)),
             ],
-            out_specs=pl.BlockSpec((1, block_g), lambda i, j, s: (i, j)),
+            out_specs=pl.BlockSpec((None, 1, block_g),
+                                   lambda i, j, s: (i, 0, j)),
         ),
-        out_shape=jax.ShapeDtypeStruct((b, gb * block_g), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((b, 1, gb * block_g), jnp.float32),
         interpret=interpret,
-    )(strata.astype(jnp.int32), eta.reshape(-1, 1), h0p)
-    return out[:, :g]
+    )(strata.astype(jnp.int32), eta.reshape(b, 1, 1), h0p[:, None, :])
+    return out[:, 0, :g]
 
 
 def survival_curves_stratified(eta: jax.Array, h0: jax.Array,

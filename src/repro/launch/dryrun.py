@@ -16,8 +16,7 @@ from repro.analysis import roofline as rl                    # noqa: E402
 from repro.configs import (REGISTRY, SHAPES, TrainConfig,    # noqa: E402
                            applicable_shapes, get_config)
 from repro.launch import sharding as sh                      # noqa: E402
-from repro.launch.mesh import (make_production_mesh,         # noqa: E402
-                               mesh_context)
+from repro.launch.mesh import make_production_mesh          # noqa: E402
 from repro.models import build_model                         # noqa: E402
 from repro.train import optimizer as opt_lib                 # noqa: E402
 from repro.train.trainer import TrainState, make_train_step  # noqa: E402
@@ -80,7 +79,7 @@ def lower_cell(arch: str, shape_name: str, multi_pod: bool,
     model = build_model(cfg)
     mesh = make_production_mesh(multi_pod=multi_pod)
 
-    with mesh_context(mesh):
+    with jax.set_mesh(mesh):
         batch_shape = model.make_input_specs(shape)
         batch = _sds_with_shardings(batch_shape,
                                     sh.batch_shardings(batch_shape, mesh))

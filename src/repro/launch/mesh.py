@@ -5,60 +5,20 @@ touches jax device state. The multi-pod mesh's leading ``pod`` axis is pure
 data parallelism: the only cross-pod traffic in a train step is the gradient
 all-reduce, which is what the (slower) DCN between pods can sustain.
 
-Also the jax-version compat seam for SPMD entry points: ``shard_map``
-moved from ``jax.experimental.shard_map`` into the top-level namespace and
-``axis_types`` only exists on newer ``jax.make_mesh`` — every sharded
-caller in the repo (core/distributed.py, serving/engine.py) goes through
-``shard_map_compat`` / ``_make_mesh`` instead of touching jax directly.
+Every mesh has ``Auto`` axis types. SPMD callers (core/distributed.py,
+serving/engine.py) wrap their per-shard bodies in
+``jax.shard_map(..., check_vma=False)``: the varying-manual-axes checker
+has no rule for ``pallas_call``, and the serving curve kernel runs
+per shard.
 """
 from __future__ import annotations
 
 import jax
 
-if hasattr(jax, "shard_map"):
-    _shard_map = jax.shard_map
-else:  # jax < 0.5
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-
-def shard_map_compat(f, mesh, in_specs, out_specs, check_rep: bool = False):
-    """``shard_map`` across jax versions.
-
-    ``check_rep=False`` by default: the replication checker has no rule for
-    ``pallas_call`` (the serving curve kernel runs per-shard), and newer jax
-    renamed the knob — fall back to calling without it when unsupported.
-    """
-    try:
-        return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                          out_specs=out_specs, check_rep=check_rep)
-    except TypeError:
-        return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                          out_specs=out_specs)
-
-
-def mesh_context(mesh):
-    """Ambient-mesh context manager across jax versions.
-
-    Newer jax wants ``jax.set_mesh(mesh)`` (or ``jax.sharding.use_mesh``);
-    on 0.4.x neither exists and the ``Mesh`` object is its own context
-    manager (``with mesh:``), which populates the thread-resources
-    physical mesh that ``models/compat.get_abstract_mesh`` falls back to.
-    """
-    setter = getattr(jax, "set_mesh", None)
-    if setter is None:
-        setter = getattr(jax.sharding, "use_mesh", None)
-    if setter is not None:
-        return setter(mesh)
-    return mesh
-
 
 def _make_mesh(shape, axes):
-    """jax.make_mesh with Auto axis_types when this jax version has them."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is not None:
-        return jax.make_mesh(shape, axes,
-                             axis_types=(axis_type.Auto,) * len(axes))
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

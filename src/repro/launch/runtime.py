@@ -1,16 +1,22 @@
-"""Runtime launch policy: env / XLA-flag / dtype tuning idioms.
+"""Runtime launch policy: env / XLA-flag tuning idioms.
 
 The HomebrewNLP-style recipe (SNIPPETS.md): tcmalloc preload, silenced
-TF/XLA logging, an explicit ``JAX_DEFAULT_DTYPE_BITS=32`` dtype policy,
-and merged (never clobbered) ``XLA_FLAGS``. ``apply()`` setdefaults the
-policy into ``os.environ`` and must run **before** jax is imported —
+TF/XLA logging and merged (never clobbered) ``XLA_FLAGS``. ``apply()``
+setdefaults the policy into ``os.environ`` and must run **before** jax is
+imported —
 ``scripts/launch.sh`` applies the same policy from the shell, which is the
 only place the tcmalloc ``LD_PRELOAD`` can happen (a running process
 cannot re-preload its allocator; ``apply()`` just reports availability).
 
-Used by ``benchmarks/run.py`` and ``examples/serve_risk_api.py``; both log
-the effective environment via ``log()`` so every recorded benchmark is
-attributable to a concrete runtime configuration.
+``apply()`` also gives JAX a persistent compilation cache: the directory
+``$JAX_COMPILATION_CACHE_DIR`` names when it is set (JAX reads it itself),
+else ``.jax_cache`` at the root of this checkout. The path is part of the
+cache key, so it is fixed, never temporary.
+
+Used by ``benchmarks/run.py``, ``chip_smoke.py`` and
+``examples/serve_risk_api.py``; they log the effective environment via
+``log()`` so every recorded benchmark is attributable to a concrete
+runtime configuration.
 """
 from __future__ import annotations
 
@@ -27,9 +33,13 @@ TCMALLOC_PATHS = (
 
 ENV_DEFAULTS: Dict[str, str] = {
     "TF_CPP_MIN_LOG_LEVEL": "4",               # silence TF/XLA chatter
-    "JAX_DEFAULT_DTYPE_BITS": "32",            # f32 policy, no implicit x64
     "TCMALLOC_LARGE_ALLOC_REPORT_THRESHOLD": "60000000000",
 }
+
+COMPILE_CACHE_ENV = "JAX_COMPILATION_CACHE_DIR"
+COMPILE_CACHE_DEFAULT = os.path.normpath(os.path.join(
+    os.path.dirname(os.path.abspath(__file__)), "..", "..", "..",
+    ".jax_cache"))
 
 # deployment-specific XLA flags go here (merged into $XLA_FLAGS, existing
 # user flags win); empty by default — the CPU container needs none
@@ -77,11 +87,13 @@ def apply(extra_env: Optional[Dict[str, str]] = None,
 
     Returns the keys actually set (existing values are never overridden).
     Call before importing jax; a late call is detected and flagged in the
-    returned dict under ``"_late"`` since env-derived config (dtype bits,
-    XLA flags) is read at import/backend-init time.
+    returned dict under ``"_late"`` since env-derived config (the compile
+    cache directory, XLA flags) is read at import/backend-init time.
     """
     applied: Dict[str, str] = {}
-    for k, v in {**ENV_DEFAULTS, **(extra_env or {})}.items():
+    defaults = {**ENV_DEFAULTS, COMPILE_CACHE_ENV: COMPILE_CACHE_DEFAULT,
+                **(extra_env or {})}
+    for k, v in defaults.items():
         if k not in os.environ:
             os.environ[k] = v
             applied[k] = v
@@ -100,11 +112,8 @@ def describe() -> Dict[str, object]:
     """The effective runtime environment (imports jax lazily)."""
     import jax
 
-    from ..models import compat as models_compat
-
     tc = find_tcmalloc()
     return {
-        "mesh_probe": models_compat.MESH_PROBE,
         "backend": jax.default_backend(),
         "device_count": jax.device_count(),
         "jax_version": jax.__version__,
@@ -114,6 +123,7 @@ def describe() -> Dict[str, object]:
         "xla_flags": os.environ.get("XLA_FLAGS", ""),
         "env": {k: os.environ.get(k, "") for k in ENV_DEFAULTS},
         "tune_cache": os.environ.get("REPRO_TUNE_CACHE", "(default)"),
+        "compile_cache": os.environ.get(COMPILE_CACHE_ENV, "(off)"),
         "data_shards": data_shards() or "(auto)",
         "stream_chunk": stream_chunk(),
     }
@@ -132,16 +142,6 @@ def log(prefix: str = "[runtime]") -> Dict[str, object]:
     if not tcmalloc_active() and find_tcmalloc():
         print(f"{prefix} note: tcmalloc present but not preloaded — "
               "launch via scripts/launch.sh to enable it", flush=True)
-    if d["mesh_probe"] != "abstract":
-        # loud on purpose: the last silent API drift here
-        # (jax.sharding.get_abstract_mesh missing on 0.4.37) took out all
-        # 41 model-zoo tests — surface the compat seam in every snapshot
-        from ..models import compat as models_compat
-
-        print(f"{prefix} WARNING: jax {d['jax_version']} has no public "
-              "mesh probe; pspec.constrain is on the thread-resources "
-              "physical-mesh fallback (supported floor: jax >= "
-              f"{models_compat.JAX_FLOOR})", flush=True)
     from ..obs import events as obs_events
 
     obs_events.emit("runtime.env", **{k: v for k, v in d.items()})

@@ -25,7 +25,7 @@ from ..train import fault_tolerance as ft
 from ..train.optimizer import init_opt_state
 from ..train.trainer import TrainState, make_train_step
 from . import sharding as sh
-from .mesh import make_host_mesh, make_production_mesh, mesh_context
+from .mesh import make_host_mesh, make_production_mesh
 
 
 def build_state(model, objective: str, rng):
@@ -84,7 +84,7 @@ def main(argv=None):
     checkpointer = ckpt_lib.AsyncCheckpointer(args.ckpt_dir) \
         if args.ckpt_dir else None
 
-    with mesh_context(mesh):
+    with jax.set_mesh(mesh):
         init = lambda: build_state(model, args.objective,
                                    jax.random.PRNGKey(args.seed))
         if args.ckpt_dir:

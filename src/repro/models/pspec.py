@@ -2,20 +2,17 @@
 
 ``constrain(x, "dp", None, "model")`` resolves "dp" to ("pod","data") when
 the ambient mesh has a pod axis, checks divisibility per dim, and no-ops
-entirely when tracing without a mesh (CPU unit tests). These anchors
-stop GSPMD from replicating the token dimension when weight shardings win
-the propagation contest (observed: without the post-embedding anchor, every
+entirely when no mesh is set (CPU unit tests). These anchors stop GSPMD
+from replicating the token dimension when weight shardings win the
+propagation contest (observed: without the post-embedding anchor, every
 per-layer GEMM ran on the full global batch per device).
 
-The mesh probe itself goes through ``compat.get_abstract_mesh`` — the
-JAX-version seam — never ``jax.sharding`` directly.
+The ambient mesh is the one ``jax.set_mesh`` installs.
 """
 from __future__ import annotations
 
 import jax
-from jax.sharding import PartitionSpec as P
-
-from . import compat
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 # experiment knob (§Perf A6/B2): resolve "dp" to include the model axis
 # (pure-DP layouts that use every chip for batch parallelism)
@@ -23,7 +20,9 @@ DP_INCLUDE_MODEL = False
 
 
 def _mesh():
-    return compat.get_abstract_mesh()
+    """The ambient mesh's axis names/sizes, or None when no mesh is set."""
+    am = jax.sharding.get_abstract_mesh()
+    return am if am.axis_names else None
 
 
 def resolve_spec(spec, shape, names, sizes, *,
@@ -60,6 +59,10 @@ def constrain(x, *spec):
     am = _mesh()
     if am is None:
         return x
-    resolved = resolve_spec(spec, x.shape, tuple(am.axis_names),
-                            zip(am.axis_names, am.axis_sizes))
-    return jax.lax.with_sharding_constraint(x, P(*resolved))
+    target = P(*resolve_spec(spec, x.shape, tuple(am.axis_names),
+                             zip(am.axis_names, am.axis_sizes)))
+    if not isinstance(x, jax.core.Tracer):
+        # outside a trace the target must name the devices of the mesh
+        # ``jax.set_mesh`` holds; a bare spec would bind the abstract mesh
+        target = NamedSharding(jax.sharding.get_mesh(), target)
+    return jax.lax.with_sharding_constraint(x, target)

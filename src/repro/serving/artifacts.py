@@ -218,7 +218,7 @@ def fit_survival_model(x: np.ndarray, t: np.ndarray, delta: np.ndarray,
     t = np.asarray(t, np.float32)
     delta = np.asarray(delta, np.float32)
     beta = np.asarray(beta, np.float32)
-    eta = np.asarray(jnp.asarray(x) @ jnp.asarray(beta), np.float32)
+    eta = x @ beta
     if time_grid is None:
         time_grid = np.linspace(float(t.min()), float(t.max()),
                                 grid_size, dtype=np.float32)

@@ -159,15 +159,21 @@ class ScoringEngine:
         use_kernel = self.use_kernel and h0.shape[0] == 1
         use_strat = self.use_strat_kernel and h0.shape[0] > 1
 
+        def lin(xb, beta):
+            # f32 as the artifact promises: TPU's default matmul precision
+            # rounds the operands to bf16
+            return jnp.dot(xb, beta, precision=jax.lax.Precision.HIGHEST)
+
         def eta_of(xb, beta):
-            return jnp.clip(xb @ beta, -_ETA_CLIP, _ETA_CLIP)
+            return jnp.clip(lin(xb, beta), -_ETA_CLIP, _ETA_CLIP)
 
         def curves(xb, beta, strata):
             if use_kernel:
-                return ops.survival_curves(xb @ beta, h0[0])
+                return ops.survival_curves(lin(xb, beta), h0[0])
             if use_strat:
                 # baseline-row gather folded into the kernel's index map
-                return ops.survival_curves_stratified(xb @ beta, h0, strata)
+                return ops.survival_curves_stratified(lin(xb, beta), h0,
+                                                      strata)
             if h0.shape[0] == 1:
                 # single stratum: broadcast the one baseline row instead of
                 # materializing a (b, g) gather panel
@@ -216,10 +222,10 @@ class ScoringEngine:
         The bucketed batch is divisible by the shard count by
         construction (see ``_pad``), so every shard runs the same
         pow-2-shaped pure body; outputs concatenate along rows."""
-        return launch_mesh.shard_map_compat(
+        return jax.shard_map(
             fn, mesh=self._mesh,
             in_specs=(P("data"), P(), P("data")),
-            out_specs=self._OUT_SPECS[kind])
+            out_specs=self._OUT_SPECS[kind], check_vma=False)
 
     def _run(self, kind: str, x, strata):
         with trace.span("engine.score", kind=kind) as sp_span:

@@ -1,10 +1,8 @@
-"""pspec.constrain contract + the models/compat mesh-probe seam.
+"""pspec.constrain contract under the ambient mesh of ``jax.set_mesh``.
 
-The regression class under test: JAX 0.4.37 has no public
-``jax.sharding.get_abstract_mesh``, and the raw call killed all 41
-model-zoo tests with one AttributeError. The seam must (a) no-op without
-a mesh, (b) resolve through whichever probe this JAX version has, and
-(c) keep working when the public probe disappears again.
+The mesh probe must (a) no-op without a mesh, (b) resolve logical axis
+names against the mesh ``jax.set_mesh`` installs, and (c) constrain both
+traced values and concrete arrays outside a trace.
 """
 import numpy as np
 
@@ -12,7 +10,8 @@ import jax
 import jax.numpy as jnp
 import pytest
 
-from repro.models import compat, pspec
+from repro.launch.mesh import make_host_mesh
+from repro.models import pspec
 
 
 # -- resolve_spec: pure resolution logic (no mesh required) -----------------
@@ -69,72 +68,32 @@ def test_constrain_no_mesh_inside_jit():
     np.testing.assert_array_equal(np.asarray(out), 2.0)
 
 
-def test_constrain_under_ambient_mesh():
-    """With a real 1-device mesh ambient, constrain must go through
-    with_sharding_constraint (and stay numerically a no-op)."""
-    from repro.launch.mesh import mesh_context, make_host_mesh
-    mesh = make_host_mesh()
+@pytest.mark.parametrize("mode", ["eager", "jit"])
+def test_constrain_under_ambient_mesh(mode):
+    """With a real 1-device mesh set, constrain must go through
+    with_sharding_constraint (and stay numerically a no-op), whether it
+    sees a concrete array or a tracer."""
     x = jnp.arange(8.0).reshape(4, 2)
-    with mesh_context(mesh):
-        am = compat.get_abstract_mesh()
-        assert am is not None
-        assert set(("data", "model")) <= set(am.axis_names)
-        y = pspec.constrain(x, "dp", "model")
+    fn = lambda v: pspec.constrain(v, "dp", "model")
+    if mode == "jit":
+        fn = jax.jit(fn)
+    with jax.set_mesh(make_host_mesh()):
+        y = fn(x)
+    assert set(y.sharding.mesh.axis_names) == {"data", "model"}
     np.testing.assert_array_equal(np.asarray(y), np.asarray(x))
 
 
-# -- compat seam: probe order + missing-API regression ----------------------
-
-def test_compat_returns_none_outside_any_mesh():
-    assert compat.get_abstract_mesh() is None
+def test_mesh_probe_none_outside_any_mesh():
+    assert pspec._mesh() is None
 
 
-def test_compat_missing_get_abstract_mesh_regression(monkeypatch):
-    """The 0.4.37 break: jax.sharding has no get_abstract_mesh. The seam
-    must resolve via the thread-resources physical mesh, not raise."""
-    monkeypatch.setattr(compat, "_PUBLIC_PROBE", None)
-    from repro.launch.mesh import mesh_context, make_host_mesh
-    assert compat.get_abstract_mesh() is None       # still no mesh -> None
-    with mesh_context(make_host_mesh()):
-        am = compat.get_abstract_mesh()
+def test_mesh_probe_sees_set_mesh():
+    with jax.set_mesh(make_host_mesh()):
+        am = pspec._mesh()
         assert am is not None
         assert dict(zip(am.axis_names, am.axis_sizes)) == {"data": 1,
                                                            "model": 1}
-
-
-def test_compat_prefers_public_probe(monkeypatch):
-    """When a public probe exists it wins over the physical fallback."""
-
-    class FakeMesh:
-        axis_names = ("pod", "data")
-        axis_sizes = (2, 8)
-
-    am = compat.get_abstract_mesh(probe=lambda: FakeMesh())
-    assert am.axis_names == ("pod", "data")
-
-
-def test_compat_empty_abstract_mesh_falls_through():
-    """A probe returning an unset/empty mesh (0.4.x private API returns
-    ``()``) must fall through to the physical mesh, not be trusted."""
-    assert compat.get_abstract_mesh(probe=lambda: ()) is None
-    from repro.launch.mesh import mesh_context, make_host_mesh
-    with mesh_context(make_host_mesh()):
-        am = compat.get_abstract_mesh(probe=lambda: ())
-        assert am is not None and "data" in am.axis_names
-
-
-def test_compat_probe_raising_attributeerror_is_survivable():
-    def broken():
-        raise AttributeError("module 'jax.sharding' has no attribute ...")
-
-    assert compat.get_abstract_mesh(probe=broken) is None
-
-
-def test_mesh_probe_status_shape():
-    st = compat.mesh_probe_status()
-    assert st["probe"] in ("abstract", "physical-fallback")
-    assert st["ambient_axes"] == ()
-    assert isinstance(st["jax_floor"], str)
+    assert pspec._mesh() is None
 
 
 def test_constrain_resolves_pod_dp_spec():
@@ -155,7 +114,7 @@ def test_constrain_resolves_pod_dp_spec():
     pspec._mesh = lambda: FakeMesh()
     jax.lax.with_sharding_constraint = fake_constrain
     try:
-        pspec.constrain(jnp.ones((8, 5)), "dp", "data")
+        jax.jit(lambda v: pspec.constrain(v, "dp", "data"))(jnp.ones((8, 5)))
     finally:
         pspec._mesh = orig_mesh
         jax.lax.with_sharding_constraint = orig_wsc
