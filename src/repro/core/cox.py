@@ -194,22 +194,24 @@ def coord_derivs(
 
     ``xl`` is the (n,) feature column (time-sorted). ``order`` controls how
     many cumulants are formed (2 -> g,h; 3 -> also the third partial).
+    Its device work is named ``cd.stats`` (the risk-set statistics).
     """
-    w, _ = hazard_weights(eta)
-    rc0 = revcumsum(w)
-    rc1 = revcumsum(w * xl)
-    s0 = rc0[data.risk_start]
-    m1 = rc1[data.risk_start] / s0
-    g = jnp.sum(data.delta * (m1 - xl))
-    rc2 = revcumsum(w * xl * xl)
-    m2 = rc2[data.risk_start] / s0
-    h = jnp.sum(data.delta * (m2 - m1 * m1))
-    if order < 3:
-        return g, h, jnp.zeros_like(g)
-    rc3 = revcumsum(w * xl * xl * xl)
-    m3 = rc3[data.risk_start] / s0
-    c3 = jnp.sum(data.delta * (m3 + 2.0 * m1**3 - 3.0 * m2 * m1))
-    return g, h, c3
+    with jax.named_scope("cd.stats"):
+        w, _ = hazard_weights(eta)
+        rc0 = revcumsum(w)
+        rc1 = revcumsum(w * xl)
+        s0 = rc0[data.risk_start]
+        m1 = rc1[data.risk_start] / s0
+        g = jnp.sum(data.delta * (m1 - xl))
+        rc2 = revcumsum(w * xl * xl)
+        m2 = rc2[data.risk_start] / s0
+        h = jnp.sum(data.delta * (m2 - m1 * m1))
+        if order < 3:
+            return g, h, jnp.zeros_like(g)
+        rc3 = revcumsum(w * xl * xl * xl)
+        m3 = rc3[data.risk_start] / s0
+        c3 = jnp.sum(data.delta * (m3 + 2.0 * m1**3 - 3.0 * m2 * m1))
+        return g, h, c3
 
 
 # ---------------------------------------------------------------------------

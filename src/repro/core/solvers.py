@@ -76,7 +76,10 @@ def _emit(telemetry, data, it, eta, beta, beta_prev, obj, lam2) -> None:
 def _cd_sweep(data: cox.CoxData, eta: Array, beta: Array, l2c: Array,
               l3c: Array, lam1, lam2, cubic: bool,
               use_kernel: bool = False) -> Tuple[Array, Array]:
-    """One full sweep over all p coordinates (sequential, lax.fori_loop)."""
+    """One full sweep over all p coordinates (sequential, lax.fori_loop).
+
+    The device work is named: ``cd.stats`` (``cox.coord_derivs``) and
+    ``cd.update`` (the prox and the ``beta``/``eta`` update)."""
     xT = data.x.T  # (p, n)
 
     if use_kernel:
@@ -86,18 +89,21 @@ def _cd_sweep(data: cox.CoxData, eta: Array, beta: Array, l2c: Array,
         eta, beta = carry
         xl = xT[l]
         if use_kernel:
-            g, h = _kops.cox_coord_grad_hess(eta, xl, data.delta)
+            with jax.named_scope("cd.stats"):
+                g, h = _kops.cox_coord_grad_hess(eta, xl, data.delta)
         else:
             g, h, _ = cox.coord_derivs(data, eta, xl, order=2)
-        bl = beta[l]
-        a = g + 2.0 * lam2 * bl
-        if cubic:
-            step = surrogate.cubic_l1_prox(
-                a, h + 2.0 * lam2, l3c[l], bl, lam1)
-        else:
-            step = surrogate.quad_l1_prox(a, l2c[l] + 2.0 * lam2, bl, lam1)
-        beta = beta.at[l].add(step)
-        eta = eta + step * xl
+        with jax.named_scope("cd.update"):
+            bl = beta[l]
+            a = g + 2.0 * lam2 * bl
+            if cubic:
+                step = surrogate.cubic_l1_prox(
+                    a, h + 2.0 * lam2, l3c[l], bl, lam1)
+            else:
+                step = surrogate.quad_l1_prox(a, l2c[l] + 2.0 * lam2, bl,
+                                              lam1)
+            beta = beta.at[l].add(step)
+            eta = eta + step * xl
         return eta, beta
 
     return jax.lax.fori_loop(0, data.p, body, (eta, beta))
@@ -155,7 +161,8 @@ def fit_cd_tol(data: cox.CoxData, lam1: float = 0.0, lam2: float = 0.0,
         eta, beta, _, cur, it = state
         beta_prev = beta
         eta, beta = _cd_sweep(data, eta, beta, l2c, l3c, lam1, lam2, cubic)
-        obj = _objective(data, eta, beta, lam1, lam2)
+        with jax.named_scope("cd.objective"):
+            obj = _objective(data, eta, beta, lam1, lam2)
         _emit(telemetry, data, it, eta, beta, beta_prev, obj, lam2)
         return eta, beta, cur, obj, it + 1
 

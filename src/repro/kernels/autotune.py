@@ -27,7 +27,6 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..obs import events as obs_events
-from ..obs import profile as obs_profile
 from .cox_batch import cox_batch
 from .cox_coord import cox_coord
 from .lipschitz import lipschitz
@@ -292,17 +291,14 @@ def autotune(kernel: str, shape: Dict[str, int], *,
 
     inputs = _build_inputs(kernel, shape)
     timings: Dict[str, dict] = {}
-    # $REPRO_PROFILE_DIR captures the candidate timing as a TensorBoard
-    # trace, one capture per (kernel, bucket); no-op when unset
-    with obs_profile.maybe_profile(f"autotune/{key}"):
-        for cfg in candidates_for(kernel, shape):
-            us = _time_call(
-                lambda cfg=cfg: run_config(kernel, inputs, cfg, interpret),
-                reps=reps)
-            timings[_cfg_key(cfg)] = {"config": cfg, "us": us}
-            if verbose:
-                print(f"[autotune] {key} {_cfg_key(cfg)} {us:.1f}us",
-                      flush=True)
+    for cfg in candidates_for(kernel, shape):
+        us = _time_call(
+            lambda cfg=cfg: run_config(kernel, inputs, cfg, interpret),
+            reps=reps)
+        timings[_cfg_key(cfg)] = {"config": cfg, "us": us}
+        if verbose:
+            print(f"[autotune] {key} {_cfg_key(cfg)} {us:.1f}us",
+                  flush=True)
     best = min(timings.values(), key=lambda e: e["us"])
     entry = {
         "kernel": kernel,

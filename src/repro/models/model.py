@@ -55,6 +55,13 @@ def _maybe_remat(body, remat):
     return jax.checkpoint(body, policy=policy)
 
 
+def _norm(params, x: Array, eps: float) -> Array:
+    """A layer's or the final RMSNorm; its device work is named
+    ``model.norm``."""
+    with jax.named_scope("model.norm"):
+        return layers.rmsnorm(params, x, eps)
+
+
 class Model:
     def __init__(self, cfg: ModelConfig):
         self.cfg = cfg
@@ -111,13 +118,14 @@ class Model:
     # Embedding / logits
     # ------------------------------------------------------------------
     def _embed_in(self, params, batch) -> Array:
-        if "embeds" in batch:
-            x = batch["embeds"].astype(self.dt)
-        else:
-            x = params["embed"][batch["tokens"]]
-        if self.cfg.name.startswith("gemma"):
-            x = x * jnp.asarray(self.cfg.d_model ** 0.5, self.dt)
-        return pspec.constrain(x, "dp", None, None)
+        with jax.named_scope("model.embed"):
+            if "embeds" in batch:
+                x = batch["embeds"].astype(self.dt)
+            else:
+                x = params["embed"][batch["tokens"]]
+            if self.cfg.name.startswith("gemma"):
+                x = x * jnp.asarray(self.cfg.d_model ** 0.5, self.dt)
+            return pspec.constrain(x, "dp", None, None)
 
     def _logits(self, params, hidden: Array) -> Array:
         head = params["embed"].T if self.cfg.tie_embeddings \
@@ -163,7 +171,7 @@ class Model:
         def body(carry, p_l):
             h = carry
             y = ssm.mamba2_forward(
-                p_l["mamba"], layers.rmsnorm(p_l["ln"], h, cfg.rms_eps),
+                p_l["mamba"], _norm(p_l["ln"], h, cfg.rms_eps),
                 d_state=cfg.ssm_state, head_dim=cfg.ssm_head_dim,
                 expand=cfg.ssm_expand, chunk=cfg.ssm_chunk,
                 return_state=want_state)
@@ -195,7 +203,7 @@ class Model:
 
             def inner(hh, p_l):
                 y = ssm.mamba2_forward(
-                    p_l["mamba"], layers.rmsnorm(p_l["ln"], hh, cfg.rms_eps),
+                    p_l["mamba"], _norm(p_l["ln"], hh, cfg.rms_eps),
                     d_state=cfg.ssm_state, head_dim=cfg.ssm_head_dim,
                     expand=cfg.ssm_expand, chunk=cfg.ssm_chunk,
                     return_state=want_kv)
@@ -230,7 +238,7 @@ class Model:
         body = _maybe_remat(body, remat)
         h, _ = jax.lax.scan(body, src.astype(self.dt), params["enc_layers"],
                             unroll=cfg.scan_unroll)
-        return layers.rmsnorm(params["enc_norm"], h, cfg.rms_eps)
+        return _norm(params["enc_norm"], h, cfg.rms_eps)
 
     def _decoder_cross_stack(self, params, x, enc_out, want_kv: bool,
                              remat: bool = True):
@@ -269,11 +277,12 @@ class Model:
                                                 remat)
         elif cfg.family == "encdec":
             enc = self._encoder(params, batch["src_embeds"], remat)
-            x = params["embed"][batch["tokens"]]
+            with jax.named_scope("model.embed"):
+                x = params["embed"][batch["tokens"]]
             x, cache_parts = self._decoder_cross_stack(params, x, enc,
                                                        want_cache, remat)
             cache_parts = (cache_parts, enc)
-        return layers.rmsnorm(params["final_norm"], x, cfg.rms_eps), aux, \
+        return _norm(params["final_norm"], x, cfg.rms_eps), aux, \
             cache_parts
 
     # ------------------------------------------------------------------
@@ -292,9 +301,15 @@ class Model:
     def risk_scores(self, params, batch, remat: bool = True):
         """Deep-survival head: mean-pool final hidden -> risk (B,)."""
         hidden, aux, _ = self.hidden_states(params, batch, remat=remat)
+        return self.pooled_risk(params, hidden), aux
+
+    @staticmethod
+    def pooled_risk(params, hidden: Array) -> Array:
+        """Risk (B,) from the final hidden states: mean-pool, then the
+        linear Cox head."""
         pooled = hidden.mean(axis=1).astype(jnp.float32)
         return pooled @ params["cox_head"]["w"][:, 0] \
-            + params["cox_head"]["b"], aux
+            + params["cox_head"]["b"]
 
     # ------------------------------------------------------------------
     # Serving
@@ -377,7 +392,7 @@ class Model:
             def body(h, xs):
                 p_l, conv_l, st_l = xs
                 y, st = ssm.mamba2_decode_step(
-                    p_l["mamba"], layers.rmsnorm(p_l["ln"], h, cfg.rms_eps),
+                    p_l["mamba"], _norm(p_l["ln"], h, cfg.rms_eps),
                     ssm.SSMState(conv=conv_l, ssm=st_l),
                     d_state=cfg.ssm_state, head_dim=cfg.ssm_head_dim,
                     expand=cfg.ssm_expand)
@@ -405,7 +420,7 @@ class Model:
                     p_l, c_l, s_l = ys
                     y, st = ssm.mamba2_decode_step(
                         p_l["mamba"],
-                        layers.rmsnorm(p_l["ln"], hh, cfg.rms_eps),
+                        _norm(p_l["ln"], hh, cfg.rms_eps),
                         ssm.SSMState(conv=c_l, ssm=s_l),
                         d_state=cfg.ssm_state, head_dim=cfg.ssm_head_dim,
                         expand=cfg.ssm_expand)
@@ -438,7 +453,7 @@ class Model:
                           cache.xv), unroll=cfg.scan_unroll)
             new_cache = EncDecCache(k=ks, v=vs, xk=cache.xk, xv=cache.xv,
                                     length=cur + 1)
-        hidden = layers.rmsnorm(params["final_norm"], x, cfg.rms_eps)
+        hidden = _norm(params["final_norm"], x, cfg.rms_eps)
         return self._logits(params, hidden[:, 0]), new_cache
 
     # ------------------------------------------------------------------

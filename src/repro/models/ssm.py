@@ -66,38 +66,50 @@ def _causal_conv(xbc: Array, w: Array, b: Array) -> Array:
 def mamba2_forward(params, x: Array, *, d_state: int, head_dim: int = 64,
                    expand: int = 2, chunk: int = 256,
                    return_state: bool = False):
-    """x: (B, S, D) -> (y: (B, S, D)[, final SSMState])."""
+    """x: (B, S, D) -> (y: (B, S, D)[, final SSMState]).
+
+    The device work is named by part: ``ssm.in_proj``, ``ssm.conv``,
+    ``ssm.ssd`` (discretization, the chunked scan and the skip),
+    ``ssm.gated_norm`` and ``ssm.out_proj``."""
     b, s, d_model = x.shape
     d_inner, n_heads, n_groups = _split(params, d_model, d_state, head_dim,
                                         expand)
-    proj = x @ params["w_in"]
-    z, xbc, dt = jnp.split(
-        proj, [d_inner, 2 * d_inner + 2 * n_groups * d_state], axis=-1)
-    xbc = _causal_conv(xbc, params["conv_w"], params["conv_b"])
-    xs, bb, cc = jnp.split(xbc, [d_inner, d_inner + n_groups * d_state],
-                           axis=-1)
-    dt = jax.nn.softplus(dt.astype(jnp.float32) + params["dt_bias"])  # (B,S,H)
-    a = -jnp.exp(params["a_log"])                                      # (H,)
+    with jax.named_scope("ssm.in_proj"):
+        proj = x @ params["w_in"]
+        z, xbc, dt = jnp.split(
+            proj, [d_inner, 2 * d_inner + 2 * n_groups * d_state], axis=-1)
+    with jax.named_scope("ssm.conv"):
+        xbc = _causal_conv(xbc, params["conv_w"], params["conv_b"])
+        xs, bb, cc = jnp.split(xbc, [d_inner, d_inner + n_groups * d_state],
+                               axis=-1)
+    with jax.named_scope("ssm.ssd"):
+        dt = jax.nn.softplus(dt.astype(jnp.float32)
+                             + params["dt_bias"])                     # (B,S,H)
+        a = -jnp.exp(params["a_log"])                                 # (H,)
 
-    xh = xs.reshape(b, s, n_heads, head_dim)
-    bb = bb.reshape(b, s, n_groups, d_state)
-    cc = cc.reshape(b, s, n_groups, d_state)
+        xh = xs.reshape(b, s, n_heads, head_dim)
+        bb = bb.reshape(b, s, n_groups, d_state)
+        cc = cc.reshape(b, s, n_groups, d_state)
 
-    y, st = _ssd_chunked(xh, dt, a, bb, cc, chunk)
-    y = y + params["d_skip"][None, None, :, None] * xh.astype(jnp.float32)
-    y = y.reshape(b, s, d_inner).astype(x.dtype)
-    # gated RMSNorm (mamba2): norm(y * silu(z))
-    g = y * jax.nn.silu(z)
-    g32 = g.astype(jnp.float32)
-    var = jnp.mean(g32 * g32, axis=-1, keepdims=True)
-    g = (g32 * jax.lax.rsqrt(var + 1e-6)).astype(x.dtype) \
-        * params["norm_scale"]
-    out = g @ params["w_out"]
+        y, st = _ssd_chunked(xh, dt, a, bb, cc, chunk)
+        y = y + params["d_skip"][None, None, :, None] \
+            * xh.astype(jnp.float32)
+        y = y.reshape(b, s, d_inner).astype(x.dtype)
+    with jax.named_scope("ssm.gated_norm"):
+        # gated RMSNorm (mamba2): norm(y * silu(z))
+        g = y * jax.nn.silu(z)
+        g32 = g.astype(jnp.float32)
+        var = jnp.mean(g32 * g32, axis=-1, keepdims=True)
+        g = (g32 * jax.lax.rsqrt(var + 1e-6)).astype(x.dtype) \
+            * params["norm_scale"]
+    with jax.named_scope("ssm.out_proj"):
+        out = g @ params["w_out"]
     if return_state:
-        conv_tail = jnp.pad(
-            (x @ params["w_in"])[:, :, d_inner:2 * d_inner
-                                 + 2 * n_groups * d_state],
-            ((0, 0), (max(0, 3 - s), 0), (0, 0)))[:, -3:, :]
+        with jax.named_scope("ssm.conv"):
+            conv_tail = jnp.pad(
+                (x @ params["w_in"])[:, :, d_inner:2 * d_inner
+                                     + 2 * n_groups * d_state],
+                ((0, 0), (max(0, 3 - s), 0), (0, 0)))[:, -3:, :]
         return out, SSMState(conv=conv_tail, ssm=st)
     return out
 

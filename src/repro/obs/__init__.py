@@ -11,8 +11,10 @@ Three layers (stdlib + the jax/numpy already in the tree; nothing else):
 
 ``events.py`` / ``trace.py``
     A JSONL event sink (``$REPRO_EVENTS_FILE``) and nested timed spans
-    with per-trace ids (``$REPRO_TRACE_FILE``), summarized into the
-    per-stage latency-breakdown table by ``repro.analysis.report``.
+    with per-trace ids (``$REPRO_TRACE_FILE``, held in memory and
+    written in blocks), summarized into the per-stage latency-breakdown
+    table by ``repro.analysis.report``. While ``jax.profiler`` traces,
+    each span is also a ``TraceAnnotation`` in the profiler's trace.
 
 ``solver.py``
     ``TelemetryCallback`` — per-iteration (objective, grad norm, step
@@ -22,21 +24,18 @@ Three layers (stdlib + the jax/numpy already in the tree; nothing else):
     through ``core/solvers.py`` and ``core/beam.py`` as a static jit
     argument: ``None`` (the default) stages nothing.
 
-``profile.py``
-    ``maybe_profile(name)`` — ``jax.profiler`` capture under
-    ``$REPRO_PROFILE_DIR``, no-op otherwise.
-
 Instrumented call sites: ``serving/service.py`` (queue/batch/dispatch
 spans, queue-depth gauge, shed/timeout counters), ``serving/engine.py``
 (compile events, bucket-size histogram), ``kernels/ops.py`` (dispatch
-counters with tuned/default tags), ``kernels/autotune.py`` (profiled
-sweeps), ``launch/runtime.py`` (env snapshot event).
+counters with tuned/default tags), ``kernels/autotune.py`` (winner
+events), ``launch/runtime.py`` (env snapshot event).
 
 Everything is overhead-free when off: disabled sinks are one ``None``
-check, disabled solver telemetry traces the pre-telemetry graph, and
+check (spans one more: is the profiler tracing), disabled solver
+telemetry traces the pre-telemetry graph, and
 metric updates on always-on counters are single locked dict writes.
 """
-from . import events, metrics, profile, trace  # noqa: F401
+from . import events, metrics, trace  # noqa: F401
 from .metrics import REGISTRY, Registry, serve_metrics  # noqa: F401
 from .solver import TelemetryCallback, emit_iter  # noqa: F401
 from .trace import span  # noqa: F401

@@ -8,19 +8,29 @@ here, so a single file replays a run end to end.
 Disabled by default and free when disabled: ``emit()`` is a ``None``
 check. Enable by pointing ``$REPRO_EVENTS_FILE`` at a path before import
 (or any time, via ``configure(path)``); ``configure(None)`` turns it
-back off. Writes are line-buffered and serialized under a lock, so
-concurrent emitters (the serving threads) never interleave partial
-lines.
+back off. Writes are serialized under a lock, so concurrent emitters
+(the serving threads) never interleave partial lines. ``emit`` writes
+and flushes its line at once. Spans, tens of thousands a second on a
+traced serving path, go through ``hold``: kept in memory and written in
+blocks of ``HOLD_MAX``, and whatever is held when the sink is closed
+(``configure`` closes the sink it replaces), when ``flush()`` is called,
+or when the process exits. ``read_jsonl`` calls ``flush()`` first, so a
+file read while its sink is still open holds every record.
 """
 from __future__ import annotations
 
+import atexit
 import json
 import os
 import threading
 import time
-from typing import IO, Optional
+import weakref
+from typing import IO, List, Optional
 
 ENV_VAR = "REPRO_EVENTS_FILE"
+HOLD_MAX = 4096   # held records written in one block
+
+_OPEN: "weakref.WeakSet[JsonlSink]" = weakref.WeakSet()
 
 
 class JsonlSink:
@@ -33,6 +43,8 @@ class JsonlSink:
             os.makedirs(d, exist_ok=True)
         self._f: IO[str] = open(path, "a")
         self._lock = threading.Lock()
+        self._held: List[dict] = []
+        _OPEN.add(self)
 
     def emit(self, kind: str, **fields) -> None:
         rec = {"ts": time.time(), "kind": kind}
@@ -42,10 +54,46 @@ class JsonlSink:
             self._f.write(line + "\n")
             self._f.flush()
 
+    def hold(self, kind: str, **fields) -> None:
+        """Keep a record in memory; it is written with the others held,
+        once ``HOLD_MAX`` are, or on ``close``."""
+        rec = {"ts": time.time(), "kind": kind}
+        rec.update(fields)
+        with self._lock:
+            self._held.append(rec)
+            if len(self._held) >= HOLD_MAX:
+                self._write_held()
+
+    def _write_held(self) -> None:
+        held, self._held = self._held, []
+        if held and not self._f.closed:
+            self._f.write("".join(json.dumps(r, default=_jsonable) + "\n"
+                                  for r in held))
+            self._f.flush()
+
+    def flush(self) -> None:
+        """Write out what is held; the sink stays open."""
+        with self._lock:
+            self._write_held()
+
     def close(self) -> None:
         with self._lock:
+            self._write_held()
             if not self._f.closed:
                 self._f.close()
+
+
+def flush() -> None:
+    """Write out what every open sink holds."""
+    for sink in list(_OPEN):
+        sink.flush()
+
+
+@atexit.register
+def _close_all() -> None:
+    """Write out what every open sink holds when the process exits."""
+    for sink in list(_OPEN):
+        sink.close()
 
 
 def _jsonable(o):
@@ -97,7 +145,9 @@ def enabled() -> bool:
 
 
 def read_jsonl(path: str):
-    """Parse a JSONL file, skipping blank/corrupt lines (analysis helper)."""
+    """Parse a JSONL file, skipping blank/corrupt lines (analysis helper).
+    What this process's sinks hold is written out first."""
+    flush()
     out = []
     with open(path) as f:
         for line in f:

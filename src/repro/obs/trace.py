@@ -10,10 +10,19 @@ can also be opened with an explicit ``trace_id`` (the serving loop tags
 every batch's trace onto its responses).
 
 Export goes to the span sink: ``$REPRO_TRACE_FILE`` when set, else the
-shared event sink (``events.py``), else nowhere. Disabled tracing costs
-one ``None`` check per ``span()`` call — the serving hot path stays
-unperturbed when observability is off (<2% is the budgeted regression;
-a no-op singleton context manager keeps it far below that).
+shared event sink (``events.py``), else nowhere. The sink holds spans in
+memory and writes them in blocks (``events.JsonlSink.hold``); each record
+carries its start on the wall clock (``start_ts``) and its duration.
+
+While ``jax.profiler`` traces, every span is also a
+``jax.profiler.TraceAnnotation`` of the same name: ``service.step``,
+``engine.score``, ``beam.*`` appear in the profiler's host plane, on the
+device trace's clock, with or without a sink.
+
+Disabled tracing (no sink, no profiler) costs one ``None`` check and one
+``TraceAnnotation.is_enabled()`` per ``span()`` call and returns a shared
+no-op — the serving hot path stays unperturbed when observability is off
+(<2% is the budgeted regression).
 
 ``repro.analysis.report.latency_breakdown_table`` summarizes a span
 JSONL file into per-stage latency totals/percentiles.
@@ -26,9 +35,14 @@ import time
 import uuid
 from typing import Optional
 
+from jax.profiler import TraceAnnotation
+
 from . import events
 
 ENV_VAR = "REPRO_TRACE_FILE"
+
+# True while jax.profiler traces (the profiler's own TraceMe check)
+_profiling = TraceAnnotation.is_enabled
 
 _LOCAL = threading.local()
 _LOCK = threading.Lock()
@@ -101,10 +115,10 @@ _NOOP = _NoopSpan()
 
 class Span:
     __slots__ = ("name", "attrs", "trace_id", "span_id", "parent_id",
-                 "_t0", "_sink")
+                 "_t0", "_wall", "_sink", "_annotation")
 
-    def __init__(self, name: str, attrs: dict, sink: events.JsonlSink,
-                 trace_id: Optional[str]):
+    def __init__(self, name: str, attrs: dict,
+                 sink: Optional[events.JsonlSink], trace_id: Optional[str]):
         self.name = name
         self.attrs = attrs
         self._sink = sink
@@ -115,6 +129,8 @@ class Span:
                          or new_trace_id())
         self.span_id = uuid.uuid4().hex[:16]
         self._t0 = 0.0
+        self._wall = 0.0
+        self._annotation = None
 
     def set(self, **attrs):
         """Attach attributes mid-span (recorded at exit)."""
@@ -123,29 +139,40 @@ class Span:
 
     def __enter__(self):
         _stack().append(self)
+        if _profiling():
+            self._annotation = TraceAnnotation(self.name)
+            self._annotation.__enter__()
+        self._wall = time.time()
         self._t0 = time.perf_counter()
         return self
 
     def __exit__(self, exc_type, exc, tb):
         dur = time.perf_counter() - self._t0
+        if self._annotation is not None:
+            self._annotation.__exit__(exc_type, exc, tb)
+            self._annotation = None
         st = _stack()
         if st and st[-1] is self:
             st.pop()
+        if self._sink is None:
+            return False
         rec = {"name": self.name, "trace_id": self.trace_id,
                "span_id": self.span_id, "parent_id": self.parent_id,
-               "dur_s": dur, "thread": threading.current_thread().name}
+               "start_ts": self._wall, "dur_s": dur,
+               "thread": threading.current_thread().name}
         if exc_type is not None:
             rec["error"] = exc_type.__name__
         if self.attrs:
             rec["attrs"] = self.attrs
-        self._sink.emit("span", **rec)
+        self._sink.hold("span", **rec)
         return False
 
 
 def span(name: str, trace_id: Optional[str] = None, **attrs):
-    """Open a timed span; returns a no-op when tracing is disabled."""
+    """Open a timed span; returns a no-op when there is no sink and the
+    profiler is not tracing."""
     sink = _sink()
-    if sink is None:
+    if sink is None and not _profiling():
         return _NOOP
     return Span(name, attrs, sink, trace_id)
 
@@ -163,13 +190,14 @@ def emit_span(name: str, dur_s: float, trace_id: Optional[str] = None,
         return
     st = _stack()
     parent = st[-1] if st else None
+    dur_s = float(dur_s)
     rec = {"name": name,
            "trace_id": (trace_id or (parent.trace_id if parent else None)
                         or new_trace_id()),
            "span_id": uuid.uuid4().hex[:16],
            "parent_id": parent_id or (parent.span_id if parent else None),
-           "dur_s": float(dur_s),
+           "start_ts": time.time() - dur_s, "dur_s": dur_s,
            "thread": threading.current_thread().name}
     if attrs:
         rec["attrs"] = attrs
-    sink.emit("span", **rec)
+    sink.hold("span", **rec)

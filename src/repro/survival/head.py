@@ -41,11 +41,16 @@ def cox_partial_likelihood(eta: Array, time: Array, event: Array) -> Array:
 
 
 def cox_loss(model: Model, params, batch):
-    """Survival objective for trainer.make_train_step(objective='cox')."""
-    eta, aux = model.risk_scores(params, batch)
-    loss = cox_partial_likelihood(eta.astype(jnp.float32),
-                                  batch["time"], batch["event"])
-    return loss + 0.01 * aux, {"cox_nll": loss, "aux": aux}
+    """Survival objective for trainer.make_train_step(objective='cox').
+
+    The pooling and the partial likelihood are named ``cox.head`` on the
+    device."""
+    hidden, aux, _ = model.hidden_states(params, batch)
+    with jax.named_scope("cox.head"):
+        eta = model.pooled_risk(params, hidden)
+        loss = cox_partial_likelihood(eta.astype(jnp.float32),
+                                      batch["time"], batch["event"])
+        return loss + 0.01 * aux, {"cox_nll": loss, "aux": aux}
 
 
 def pooled_features(model: Model, params, batch) -> Array:

@@ -44,33 +44,35 @@ def global_norm(tree) -> jax.Array:
 
 
 def adamw_update(grads, opt: OptState, params, cfg: TrainConfig):
-    """Returns (new_params, new_opt, metrics)."""
-    step = opt.step + 1
-    gnorm = global_norm(grads)
-    scale = jnp.minimum(1.0, cfg.grad_clip / (gnorm + 1e-9))
-    lr = lr_schedule(step, cfg)
-    b1, b2 = cfg.beta1, cfg.beta2
-    bc1 = 1.0 - b1 ** step.astype(jnp.float32)
-    bc2 = 1.0 - b2 ** step.astype(jnp.float32)
+    """Returns (new_params, new_opt, metrics); the device work (the clip
+    and the update) is named ``optim.adamw``."""
+    with jax.named_scope("optim.adamw"):
+        step = opt.step + 1
+        gnorm = global_norm(grads)
+        scale = jnp.minimum(1.0, cfg.grad_clip / (gnorm + 1e-9))
+        lr = lr_schedule(step, cfg)
+        b1, b2 = cfg.beta1, cfg.beta2
+        bc1 = 1.0 - b1 ** step.astype(jnp.float32)
+        bc2 = 1.0 - b2 ** step.astype(jnp.float32)
 
-    def upd(g, m, v, p):
-        g = g.astype(jnp.float32) * scale
-        m = b1 * m + (1 - b1) * g
-        v = b2 * v + (1 - b2) * g * g
-        mh = m / bc1
-        vh = v / bc2
-        delta = mh / (jnp.sqrt(vh) + 1e-8) + cfg.weight_decay \
-            * p.astype(jnp.float32)
-        return (p.astype(jnp.float32) - lr * delta).astype(p.dtype), m, v
+        def upd(g, m, v, p):
+            g = g.astype(jnp.float32) * scale
+            m = b1 * m + (1 - b1) * g
+            v = b2 * v + (1 - b2) * g * g
+            mh = m / bc1
+            vh = v / bc2
+            delta = mh / (jnp.sqrt(vh) + 1e-8) + cfg.weight_decay \
+                * p.astype(jnp.float32)
+            return (p.astype(jnp.float32) - lr * delta).astype(p.dtype), m, v
 
-    flat_p, tdef = jax.tree.flatten(params)
-    flat_g = tdef.flatten_up_to(grads)
-    flat_m = tdef.flatten_up_to(opt.m)
-    flat_v = tdef.flatten_up_to(opt.v)
-    out = [upd(g, m, v, p)
-           for g, m, v, p in zip(flat_g, flat_m, flat_v, flat_p)]
-    new_p = tdef.unflatten([o[0] for o in out])
-    new_m = tdef.unflatten([o[1] for o in out])
-    new_v = tdef.unflatten([o[2] for o in out])
-    return new_p, OptState(m=new_m, v=new_v, step=step), \
-        {"grad_norm": gnorm, "lr": lr}
+        flat_p, tdef = jax.tree.flatten(params)
+        flat_g = tdef.flatten_up_to(grads)
+        flat_m = tdef.flatten_up_to(opt.m)
+        flat_v = tdef.flatten_up_to(opt.v)
+        out = [upd(g, m, v, p)
+               for g, m, v, p in zip(flat_g, flat_m, flat_v, flat_p)]
+        new_p = tdef.unflatten([o[0] for o in out])
+        new_m = tdef.unflatten([o[1] for o in out])
+        new_v = tdef.unflatten([o[2] for o in out])
+        return new_p, OptState(m=new_m, v=new_v, step=step), \
+            {"grad_norm": gnorm, "lr": lr}

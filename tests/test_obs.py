@@ -167,6 +167,69 @@ def test_span_noop_when_disabled(sinks_off):
         s.set(more=2)
 
 
+def test_span_is_the_shared_noop_without_sink_or_profiler(sinks_off):
+    """No sink and no profiler tracing: ``span()`` hands back the one
+    shared no-op, so the hot path allocates nothing."""
+    assert not trace._profiling()
+    assert trace.span("service.step") is trace._NOOP
+    assert trace.span("engine.score", b=3) is trace._NOOP
+
+
+def test_held_spans_are_written_when_the_sink_is_reconfigured(
+        tmp_path, sinks_off):
+    """Spans wait in memory, not on disk, and none is lost when the sink
+    is replaced: a full block is written as it fills, the rest on
+    ``configure(None)``."""
+    path = str(tmp_path / "trace.jsonl")
+    trace.configure(path)
+    try:
+        with trace.span("first"):
+            pass
+        trace.emit_span("retro", 0.5)
+        assert os.path.getsize(path) == 0       # held, not written
+        n = events.HOLD_MAX + 10
+        for i in range(n):
+            with trace.span("service.dispatch", i=i):
+                pass
+        with open(path) as f:                   # one block written
+            assert sum(1 for _ in f) == events.HOLD_MAX
+    finally:
+        trace.configure(None)
+    recs = events.read_jsonl(path)
+    assert len(recs) == n + 2
+    assert sorted(r["attrs"]["i"] for r in recs if "attrs" in r) \
+        == list(range(n))
+    first = recs[0]
+    assert first["name"] == "first"
+    assert first["start_ts"] <= first["ts"]
+    retro = next(r for r in recs if r["name"] == "retro")
+    assert retro["ts"] - retro["start_ts"] == pytest.approx(0.5, abs=1e-3)
+
+
+def test_a_span_file_read_while_its_sink_is_open_holds_every_span(
+        tmp_path, sinks_off):
+    """A reader in the same process, the sink still open (as
+    ``examples/serve_risk_api.py`` renders its table), sees every finished
+    span; closing the sink afterwards writes none of them twice."""
+    from repro.analysis.report import latency_breakdown_table
+    path = str(tmp_path / "trace.jsonl")
+    trace.configure(path)
+    try:
+        with trace.span("service.step"):
+            for _ in range(3):
+                with trace.span("service.dispatch"):
+                    pass
+        trace.emit_span("service.request", 0.01)
+        lines = latency_breakdown_table(path).splitlines()
+        assert any(ln.startswith("| service.step | 1 ") for ln in lines)
+        assert any(ln.startswith("| service.dispatch | 3 ") for ln in lines)
+        assert any(ln.startswith("| service.request | 1 ") for ln in lines)
+        assert len(events.read_jsonl(path)) == 5
+    finally:
+        trace.configure(None)
+    assert len(events.read_jsonl(path)) == 5
+
+
 def test_span_nesting_and_trace_ids(tmp_path, sinks_off):
     path = str(tmp_path / "trace.jsonl")
     trace.configure(path)
