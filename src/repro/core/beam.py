@@ -46,11 +46,12 @@ def score_candidates(data: cox.CoxData, eta: Array, l2c: Array,
     Returns (decrease (p,), step_total (p,)); support members get -inf.
     """
     base = cox.loss_from_eta(data, eta)
+    ev = cox.risk_start_events(data)
 
     def one(xl, l2l):
         def body(carry, _):
             eta_l, b = carry
-            g, _, _ = cox.coord_derivs(data, eta_l, xl, order=2)
+            g, _, _ = cox.coord_derivs(data, eta_l, xl, order=2, ev=ev)
             step = surrogate.quad_min(g + 2.0 * lam2 * b,
                                       l2l + 2.0 * lam2).astype(eta.dtype)
             return (eta_l + step * xl, b + step), None
@@ -77,6 +78,7 @@ def finetune(data: cox.CoxData, support_idx: Array, support_mask: Array,
     l2c, _ = cox.lipschitz_constants(
         cox.CoxData(x=cols, delta=data.delta, risk_start=data.risk_start,
                     tie_end=data.tie_end))
+    ev = cox.risk_start_events(data)
 
     def sweep(carry, _):
         eta, beta_s = carry
@@ -84,7 +86,7 @@ def finetune(data: cox.CoxData, support_idx: Array, support_mask: Array,
         def body(j, c):
             eta, beta_s = c
             xl = cols[:, j]
-            g, _, _ = cox.coord_derivs(data, eta, xl, order=2)
+            g, _, _ = cox.coord_derivs(data, eta, xl, order=2, ev=ev)
             step = surrogate.quad_min(g + 2.0 * lam2 * beta_s[j],
                                       l2c[j] + 2.0 * lam2)
             step = jnp.where(support_mask[j] > 0, step, 0.0)

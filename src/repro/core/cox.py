@@ -19,6 +19,10 @@ Key quantities (all O(n) to form):
   d_i  = delta_i / S0_i
   A_k  = cumsum(d)[tie_end[k]]   = sum_{i : t_i <= t_k} delta_i / S0_i
   B_k  = cumsum(delta/S0^2)[tie_end[k]]
+  E_k  = sum_{i : risk_start[i] = k} delta_i (events whose risk set starts
+         at k; beta-free, so formed once per solve), which turns every
+         per-event risk-set sum into a per-position one:
+         sum_i delta_i f[risk_start[i]] = sum_k E_k f[k]
 
 Swapped-order ("GEMV") identities used for all-coordinate derivatives:
   grad      = X^T (w * A) - X^T delta
@@ -187,30 +191,52 @@ def eta_hessian_upper(data: CoxData, eta: Array) -> Array:
 # Per-coordinate derivatives (Theorem 3.1) — the paper's CD primitives
 # ---------------------------------------------------------------------------
 
+def risk_start_events(data: CoxData) -> Array:
+    """E (n,): the number of events whose risk set starts at each position.
+
+    E_k = sum_{i : risk_start[i] = k} delta_i, so for any per-position f
+    (a suffix sum, say)
+
+        sum_i delta_i f[risk_start[i]] = sum_k E_k f[k]
+
+    which trades a gather at ``risk_start`` for an elementwise weight.
+    E depends on the data alone: form it once per solve, outside any loop
+    over coordinates. On tie-free data E == delta.
+    """
+    return jnp.zeros(data.n, data.delta.dtype).at[data.risk_start].add(
+        data.delta)
+
+
 def coord_derivs(
-    data: CoxData, eta: Array, xl: Array, order: int = 2
+    data: CoxData, eta: Array, xl: Array, order: int = 2,
+    ev: Array | None = None,
 ) -> Tuple[Array, Array, Array]:
     """(g, h, c3) = 1st/2nd/3rd partial at one coordinate, each O(n).
 
     ``xl`` is the (n,) feature column (time-sorted). ``order`` controls how
     many cumulants are formed (2 -> g,h; 3 -> also the third partial).
+    ``ev`` is ``risk_start_events(data)``; loops over coordinates pass it
+    in, otherwise it is formed here. The risk-set moments are taken at
+    every position and weighted by ``ev``, so no gather is needed.
+    Positions that start no risk set add exactly 0 (their moments may be
+    0/0 where the tail's hazards underflow).
     Its device work is named ``cd.stats`` (the risk-set statistics).
     """
     with jax.named_scope("cd.stats"):
+        if ev is None:
+            ev = risk_start_events(data)
+        starts = ev > 0
         w, _ = hazard_weights(eta)
         rc0 = revcumsum(w)
-        rc1 = revcumsum(w * xl)
-        s0 = rc0[data.risk_start]
-        m1 = rc1[data.risk_start] / s0
-        g = jnp.sum(data.delta * (m1 - xl))
-        rc2 = revcumsum(w * xl * xl)
-        m2 = rc2[data.risk_start] / s0
-        h = jnp.sum(data.delta * (m2 - m1 * m1))
+        m1 = revcumsum(w * xl) / rc0
+        g = jnp.sum(jnp.where(starts, ev * m1, 0.0) - data.delta * xl)
+        m2 = revcumsum(w * xl * xl) / rc0
+        h = jnp.sum(jnp.where(starts, ev * (m2 - m1 * m1), 0.0))
         if order < 3:
             return g, h, jnp.zeros_like(g)
-        rc3 = revcumsum(w * xl * xl * xl)
-        m3 = rc3[data.risk_start] / s0
-        c3 = jnp.sum(data.delta * (m3 + 2.0 * m1**3 - 3.0 * m2 * m1))
+        m3 = revcumsum(w * xl * xl * xl) / rc0
+        k3 = m3 + 2.0 * m1**3 - 3.0 * m2 * m1
+        c3 = jnp.sum(jnp.where(starts, ev * k3, 0.0))
         return g, h, c3
 
 

@@ -74,10 +74,11 @@ def _emit(telemetry, data, it, eta, beta, beta_prev, obj, lam2) -> None:
 # ---------------------------------------------------------------------------
 
 def _cd_sweep(data: cox.CoxData, eta: Array, beta: Array, l2c: Array,
-              l3c: Array, lam1, lam2, cubic: bool,
+              l3c: Array, ev: Array, lam1, lam2, cubic: bool,
               use_kernel: bool = False) -> Tuple[Array, Array]:
     """One full sweep over all p coordinates (sequential, lax.fori_loop).
 
+    ``ev`` is ``cox.risk_start_events(data)``, formed once per solve.
     The device work is named: ``cd.stats`` (``cox.coord_derivs``) and
     ``cd.update`` (the prox and the ``beta``/``eta`` update)."""
     xT = data.x.T  # (p, n)
@@ -92,7 +93,7 @@ def _cd_sweep(data: cox.CoxData, eta: Array, beta: Array, l2c: Array,
             with jax.named_scope("cd.stats"):
                 g, h = _kops.cox_coord_grad_hess(eta, xl, data.delta)
         else:
-            g, h, _ = cox.coord_derivs(data, eta, xl, order=2)
+            g, h, _ = cox.coord_derivs(data, eta, xl, order=2, ev=ev)
         with jax.named_scope("cd.update"):
             bl = beta[l]
             a = g + 2.0 * lam2 * bl
@@ -124,12 +125,13 @@ def fit_cd(data: cox.CoxData, lam1: float = 0.0, lam2: float = 0.0,
     beta = jnp.zeros(data.p, data.x.dtype) if beta0 is None else beta0
     eta = data.x @ beta
     l2c, l3c = cox.lipschitz_constants(data)
+    ev = cox.risk_start_events(data)
 
     def step(carry, it):
         eta, beta = carry
         beta_prev = beta
-        eta, beta = _cd_sweep(data, eta, beta, l2c, l3c, lam1, lam2, cubic,
-                              use_kernel=use_kernel)
+        eta, beta = _cd_sweep(data, eta, beta, l2c, l3c, ev, lam1, lam2,
+                              cubic, use_kernel=use_kernel)
         obj = _objective(data, eta, beta, lam1, lam2)
         _emit(telemetry, data, it, eta, beta, beta_prev, obj, lam2)
         return (eta, beta), obj
@@ -151,6 +153,7 @@ def fit_cd_tol(data: cox.CoxData, lam1: float = 0.0, lam2: float = 0.0,
     beta = jnp.zeros(data.p, data.x.dtype) if beta0 is None else beta0
     eta = data.x @ beta
     l2c, l3c = cox.lipschitz_constants(data)
+    ev = cox.risk_start_events(data)
     f0 = _objective(data, eta, beta, lam1, lam2)
 
     def cond(state):
@@ -160,7 +163,8 @@ def fit_cd_tol(data: cox.CoxData, lam1: float = 0.0, lam2: float = 0.0,
     def body(state):
         eta, beta, _, cur, it = state
         beta_prev = beta
-        eta, beta = _cd_sweep(data, eta, beta, l2c, l3c, lam1, lam2, cubic)
+        eta, beta = _cd_sweep(data, eta, beta, l2c, l3c, ev, lam1, lam2,
+                              cubic)
         with jax.named_scope("cd.objective"):
             obj = _objective(data, eta, beta, lam1, lam2)
         _emit(telemetry, data, it, eta, beta, beta_prev, obj, lam2)
@@ -423,6 +427,7 @@ def fit_cd_penalized(data: cox.CoxData, penalty: str = "scad",
     beta = jnp.zeros(data.p, data.x.dtype) if beta0 is None else beta0
     eta = data.x @ beta
     l2c, _ = cox.lipschitz_constants(data)
+    ev = cox.risk_start_events(data)
     xT = data.x.T
 
     def sweep(carry, _):
@@ -430,7 +435,7 @@ def fit_cd_penalized(data: cox.CoxData, penalty: str = "scad",
 
         def body(l, c):
             eta, beta = c
-            g, _, _ = cox.coord_derivs(data, eta, xT[l], order=2)
+            g, _, _ = cox.coord_derivs(data, eta, xT[l], order=2, ev=ev)
             a = g + 2.0 * lam2 * beta[l]
             step = prox(a, l2c[l] + 2.0 * lam2, beta[l], lam1, gamma)
             return eta + step * xT[l], beta.at[l].add(step)

@@ -4,18 +4,21 @@ Interpret mode accepts block layouts that the chip's compiler refuses (a
 block whose last two dims are neither (8, 128)-aligned nor the whole
 array). These tests lower each kernel at a real shape against a v5e chip
 that is described, not attached, and require the Mosaic kernel in the
-compiled program. No device runs anything here.
+compiled program. The paper's coordinate-descent fit is compiled the same
+way, to guard what its hot loop holds. No device runs anything here.
 
 The topology is described inside a module-scoped fixture, so only the
 worker that runs this file loads the TPU compiler library.
 """
 import functools
+import re
 
 import jax
 import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro.core import cox, solvers
 from repro.kernels import autotune
 from repro.kernels.cox_batch import cox_batch
 from repro.kernels.cox_coord import cox_coord
@@ -102,3 +105,18 @@ def test_kernel_compiles_for_v5e(case, one_chip, chip_config):
                            interpret=False)
     compiled = _compile(fn, one_chip, *shapes)
     assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_coordinate_sweep_has_no_risk_set_gather(one_chip, chip_config):
+    """The paper's n = p = 1200 fit as the benchmark's fit driver calls it:
+    the per-coordinate risk-set statistics read no gather at
+    ``risk_start`` (the event weights stand in for it)."""
+    n = p = 1200
+    one = lambda s, dt: jax.ShapeDtypeStruct(s, dt, sharding=one_chip)
+    data = cox.CoxData(x=one((n, p), F32), delta=one((n,), F32),
+                       risk_start=one((n,), I32), tie_end=one((n,), I32))
+    fit = functools.partial(solvers.fit_cd_tol, lam1=1.0, lam2=1.0,
+                            max_iters=2000, tol=0.1, method="cd_quad")
+    text = jax.jit(fit).lower(data).compile().as_text()
+    assert 'op_name="' in text and "cd.stats/" in text
+    assert not re.findall(r'op_name="[^"]*cd\.stats/gather', text)

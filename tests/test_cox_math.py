@@ -133,3 +133,81 @@ def test_third_derivative_not_fourth_moment(small):
         lambda b: cox.central_moment(data, data.x @ b, xl, 3))(beta)[:, l]
     c4 = cox.central_moment(data, data.x @ beta, xl, 4)
     assert not np.allclose(np.asarray(jac3), np.asarray(c4), rtol=1e-3)
+
+
+def gather_derivs(data, eta, xl):
+    """(g, h, c3) as sums over events of the moments read at each event's
+    ``risk_start``: the per-event form of Theorem 3.1."""
+    w = jnp.exp(eta - jnp.max(eta))
+    rs = data.risk_start
+    s0 = cox.revcumsum(w)[rs]
+    m1, m2, m3 = (cox.revcumsum(w * xl**j)[rs] / s0 for j in (1, 2, 3))
+    d = data.delta
+    return (jnp.sum(d * (m1 - xl)), jnp.sum(d * (m2 - m1 * m1)),
+            jnp.sum(d * (m3 + 2.0 * m1**3 - 3.0 * m2 * m1)))
+
+
+@pytest.fixture(scope="module", params=["tied", "tie_free"])
+def cohort(request, small):
+    if request.param == "tied":
+        _, _, _, data, beta = small
+        return request.param, data, beta
+    rng = np.random.default_rng(7)
+    x = rng.standard_normal((80, 5))
+    t = rng.permutation(80).astype(np.float64) + 1.0
+    delta = (rng.uniform(size=80) < 0.6).astype(np.float64)
+    return (request.param, cox.prepare(x, t, delta),
+            jnp.asarray(rng.standard_normal(5) * 0.3))
+
+
+def test_risk_start_events(cohort):
+    kind, data, _ = cohort
+    ev = np.asarray(cox.risk_start_events(data))
+    delta = np.asarray(data.delta)
+    rs = np.asarray(data.risk_start)
+    assert ev.dtype == delta.dtype
+    np.testing.assert_allclose(ev.sum(), delta.sum(), rtol=1e-12)
+    assert np.all(ev[rs != np.arange(data.n)] == 0.0)
+    if kind == "tie_free":
+        np.testing.assert_array_equal(ev, delta)
+    else:
+        assert np.any(rs != np.arange(data.n))
+
+
+@pytest.mark.parametrize("order", [2, 3])
+def test_coord_derivs_with_events_match_gather(cohort, order):
+    _, data, beta = cohort
+    eta = data.x @ beta
+    ev = cox.risk_start_events(data)
+    for l in range(data.p):
+        xl = data.x[:, l]
+        ours = cox.coord_derivs(data, eta, xl, order=order, ev=ev)
+        ref = gather_derivs(data, eta, xl)
+        for k in range(order):
+            np.testing.assert_allclose(ours[k], ref[k], rtol=1e-5)
+
+
+def test_coord_derivs_finite_where_tail_hazards_underflow():
+    """A float32 cohort whose last tie group's hazards underflow past its
+    first member: rc0 is 0 at positions that start no risk set, and the
+    moments there are 0/0. They must add nothing."""
+    x, t, delta = make_tied_survival(n=60, p=3, n_times=12, seed=1)
+    tail = np.argsort(t)[-5:]           # one last tie group of five,
+    t[tail] = t.max()                   # two of them events
+    delta[tail[:2]] = 1.0
+    data = cox.prepare(jnp.asarray(x), jnp.asarray(t), jnp.asarray(delta))
+    start = int(data.risk_start[-1])
+    assert start == data.n - 5
+    eta = np.zeros(data.n, np.float32)
+    eta[start + 1:] = -200.0
+    eta = jnp.asarray(eta)
+    rc0 = cox.revcumsum(jnp.exp(eta - jnp.max(eta)))
+    assert float(rc0[-1]) == 0.0 and float(rc0[start]) > 0.0
+    ev = cox.risk_start_events(data)
+    for l in range(data.p):
+        xl = data.x[:, l]
+        g, h, _ = cox.coord_derivs(data, eta, xl, ev=ev)
+        g_ref, h_ref, _ = gather_derivs(data, eta, xl)
+        assert np.isfinite(float(g)) and np.isfinite(float(h))
+        np.testing.assert_allclose(g, g_ref, rtol=1e-5)
+        np.testing.assert_allclose(h, h_ref, rtol=1e-5)

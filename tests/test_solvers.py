@@ -1,12 +1,14 @@
 """Solver behaviour: monotone decrease (the paper's headline guarantee),
 agreement of every convergent method on the same convex optimum, and the
 early-stopping variant."""
+from functools import partial
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.core import cox, solvers
+from repro.core import beam, cox, solvers, surrogate
 from repro.data.synthetic import SyntheticSpec, make_correlated_survival, \
     make_tied_survival
 
@@ -108,3 +110,69 @@ def test_gd_decreases(problem):
     obj = np.asarray(res.objective)
     assert np.all(np.isfinite(obj))
     assert float(obj[-1]) < float(obj[0])
+
+
+@pytest.fixture(scope="module")
+def tied():
+    x, t, delta = make_tied_survival(n=300, p=10, n_times=15, seed=4)
+    return cox.prepare(x.astype(np.float64), t, delta)
+
+
+@partial(jax.jit, static_argnames=("n_iters",))
+def _gather_fit_cd(data, lam1, lam2, n_iters):
+    """Quadratic-surrogate CD with each coordinate's gradient summed over
+    events, the risk-set moments gathered at every event's risk_start.
+    Returns the objective after ``n_iters`` sweeps."""
+    l2c, _ = cox.lipschitz_constants(data)
+    xT = data.x.T
+    rs = data.risk_start
+
+    def coord(i, c):
+        eta, beta = c
+        l = i % data.p
+        w = jnp.exp(eta - jnp.max(eta))
+        m1 = cox.revcumsum(w * xT[l])[rs] / cox.revcumsum(w)[rs]
+        g = jnp.sum(data.delta * (m1 - xT[l]))
+        step = surrogate.quad_l1_prox(g + 2.0 * lam2 * beta[l],
+                                      l2c[l] + 2.0 * lam2, beta[l], lam1)
+        return eta + step * xT[l], beta.at[l].add(step)
+
+    zero = jnp.zeros(data.p, data.x.dtype)
+    _, beta = jax.lax.fori_loop(0, n_iters * data.p, coord,
+                                (data.x @ zero, zero))
+    return cox.objective(data, beta, lam1, lam2)
+
+
+@pytest.mark.parametrize("case", ["fit_cd", "fit_cd_tol", "fit_cd_tol_l2"])
+def test_cd_on_tied_cohort_matches_gather_form(tied, case):
+    """Weighting the suffix sums by the events that start there is the
+    per-event gather regrouped: the same objective on tied data."""
+    if case == "fit_cd":
+        f = solvers.fit_cd(tied, lam1=1.0, lam2=1.0, n_iters=40).objective[-1]
+        ref = _gather_fit_cd(tied, 1.0, 1.0, n_iters=40)
+    elif case == "fit_cd_tol":
+        f = solvers.fit_cd_tol(tied, lam1=1.0, lam2=1.0, max_iters=500,
+                               tol=1e-10).objective[0]
+        ref = _gather_fit_cd(tied, 1.0, 1.0, n_iters=500)
+    else:
+        f = solvers.fit_cd_tol(tied, lam2=1.0, max_iters=500,
+                               tol=1e-10).objective[0]
+        ref = solvers.fit_newton(tied, lam2=1.0, n_iters=40,
+                                 line_search=True).objective[-1]
+    np.testing.assert_allclose(float(f), float(ref), rtol=1e-5)
+
+
+def test_finetune_with_and_without_hoisted_events(tied, monkeypatch):
+    """beam.finetune forms the event weights once; forming them inside
+    every coordinate's derivatives gives the same support loss."""
+    idx = jnp.asarray([1, 4, 7, 0], jnp.int32)
+    mask = jnp.asarray([1.0, 1.0, 1.0, 0.0])
+    _, _, hoisted = beam.finetune(tied, idx, mask, 1e-3, 4, n_sweeps=30)
+    derivs = cox.coord_derivs
+    monkeypatch.setattr(
+        cox, "coord_derivs",
+        lambda data, eta, xl, order=2, ev=None: derivs(data, eta, xl, order))
+    inner = jax.jit(beam.finetune.__wrapped__,
+                    static_argnames=("k_max", "n_sweeps"))
+    _, _, per_coord = inner(tied, idx, mask, 1e-3, 4, n_sweeps=30)
+    np.testing.assert_allclose(float(hoisted), float(per_coord), rtol=1e-12)
