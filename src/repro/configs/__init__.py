@@ -2,14 +2,14 @@
 from .base import SHAPES, ModelConfig, ShapeSpec, TrainConfig  # noqa: F401
 
 from . import (deepseek_67b, gemma3_12b, mamba2_130m, mixtral_8x22b,
-               mixtral_8x7b, qwen1_5_4b, qwen2_5_3b, qwen2_vl_7b,
-               seamless_m4t_large_v2, zamba2_2_7b)
+               mixtral_8x7b, nemotron3_nano_30b_a3b, qwen1_5_4b, qwen2_5_3b,
+               qwen2_vl_7b, seamless_m4t_large_v2, zamba2_2_7b)
 
 REGISTRY = {
     m.CONFIG.name: m.CONFIG
     for m in (qwen2_5_3b, qwen1_5_4b, gemma3_12b, deepseek_67b,
               seamless_m4t_large_v2, mixtral_8x7b, mixtral_8x22b,
-              qwen2_vl_7b, mamba2_130m, zamba2_2_7b)
+              qwen2_vl_7b, mamba2_130m, zamba2_2_7b, nemotron3_nano_30b_a3b)
 }
 
 
@@ -46,6 +46,14 @@ def reduced_config(cfg: ModelConfig) -> ModelConfig:
         kw.update(encoder_layers=2)
     if cfg.n_experts:
         kw.update(n_experts=4)
+    if cfg.family == "pattern":
+        # one whole period (the first attention block and the blocks up to
+        # it), four groups of B/C, top-2 of 8 experts, 4 of them held
+        period = cfg.layer_pattern.index("*") + 2
+        kw.update(n_layers=period, layer_pattern=cfg.layer_pattern[:period],
+                  ssm_state=16, ssm_head_dim=16, ssm_heads=8, ssm_groups=4,
+                  ssm_chunk=16, n_experts=8, n_experts_per_tok=2,
+                  experts_first=2, experts_held=4, shared_expert_ff=96)
     if cfg.sliding_window:
         kw.update(sliding_window=16)
     if cfg.local_global_ratio:

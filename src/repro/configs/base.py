@@ -12,7 +12,7 @@ def _round_up(x: int, m: int) -> int:
 @dataclasses.dataclass(frozen=True)
 class ModelConfig:
     name: str
-    family: str                     # dense | moe | ssm | hybrid | encdec | vlm
+    family: str         # dense | moe | ssm | hybrid | pattern | encdec | vlm
     n_layers: int
     d_model: int
     n_heads: int
@@ -33,6 +33,16 @@ class ModelConfig:
     ssm_expand: int = 2
     ssm_chunk: int = 128
     shared_attn_every: int = 0      # zamba2: shared attn block period
+    # family "pattern" (Nemotron-H): one block per character, each
+    # x + mixer(RMSNorm(x)); M = Mamba-2, E = expert layer, * = attention
+    layer_pattern: str = ""
+    ssm_heads: int = 0              # >0: d_inner = ssm_heads * ssm_head_dim
+    ssm_groups: int = 1             # B/C groups; the gated norm per group
+    ssm_norm_eps: float = 1e-6      # the gated RMSNorm's epsilon
+    routed_scaling: float = 1.0     # E blocks: routed weights' scale
+    shared_expert_ff: int = 0       # E blocks: the shared expert's width
+    experts_first: int = 0          # E blocks: first expert held here
+    experts_held: int = 0           # E blocks: experts held (0: all)
     encoder_layers: int = 0         # >0 -> encoder-decoder
     mrope_sections: Tuple[int, ...] = ()
     rms_eps: float = 1e-6

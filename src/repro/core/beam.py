@@ -126,12 +126,17 @@ def beam_search(data: cox.CoxData, k: int, beam_width: int = 5,
             with trace.span("beam.size", size=size) as size_span:
                 candidates = {}
                 with trace.span("beam.score", n_beams=len(beams)):
+                    # every beam's scoring is dispatched before any is
+                    # read back, so the device runs them back to back
+                    decs = []
                     for loss_b, supp, eta_b in beams:
                         mask = np.zeros(p, dtype=bool)
                         mask[list(supp)] = True
                         dec, _ = score_candidates(data, eta_b, l2c, lam2,
                                                   jnp.asarray(mask),
                                                   steps=score_steps)
+                        decs.append((supp, dec))
+                    for supp, dec in decs:
                         top = np.argsort(-np.asarray(dec))[:n_expand]
                         for l in top:
                             new_supp = tuple(sorted(supp + (int(l),)))
@@ -142,14 +147,17 @@ def beam_search(data: cox.CoxData, k: int, beam_width: int = 5,
                 scored = []
                 with trace.span("beam.finetune",
                                 n_candidates=len(candidates)):
+                    # likewise every candidate's finetune
+                    runs = []
                     for new_supp in candidates:
                         idx = np.zeros(k, dtype=np.int32)
                         msk = np.zeros(k, dtype=np.float32)
                         idx[: len(new_supp)] = np.asarray(new_supp, np.int32)
                         msk[: len(new_supp)] = 1.0
-                        beta_s, eta, loss = finetune(
+                        runs.append((new_supp, idx, finetune(
                             data, jnp.asarray(idx), jnp.asarray(msk), lam2,
-                            k, n_sweeps=finetune_sweeps)
+                            k, n_sweeps=finetune_sweeps)))
+                    for new_supp, idx, (beta_s, eta, loss) in runs:
                         scored.append((float(loss), new_supp, eta,
                                        np.asarray(beta_s), idx))
                 scored.sort(key=lambda s: s[0])

@@ -7,6 +7,7 @@ ShapeDtypeStruct stand-ins the dry-run lowers against.
 """
 from __future__ import annotations
 
+import functools
 from typing import Any, Dict, NamedTuple, Optional, Tuple
 
 import jax
@@ -14,7 +15,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..configs.base import ModelConfig, ShapeSpec
-from . import layers, pspec, ssm, transformer as tf
+from . import layers, moe, pspec, ssm, transformer as tf
 
 Array = jax.Array
 
@@ -62,6 +63,55 @@ def _norm(params, x: Array, eps: float) -> Array:
         return layers.rmsnorm(params, x, eps)
 
 
+def _init_mamba(rng, cfg: ModelConfig, dt):
+    return ssm.init_mamba2(rng, cfg.d_model, cfg.ssm_state, cfg.ssm_head_dim,
+                           cfg.ssm_expand, dtype=dt, n_groups=cfg.ssm_groups,
+                           n_heads=cfg.ssm_heads)
+
+
+def _mamba(params, x: Array, cfg: ModelConfig, return_state: bool = False):
+    return ssm.mamba2_forward(params, x, d_state=cfg.ssm_state,
+                              head_dim=cfg.ssm_head_dim, chunk=cfg.ssm_chunk,
+                              n_groups=cfg.ssm_groups,
+                              norm_eps=cfg.ssm_norm_eps,
+                              return_state=return_state)
+
+
+def _init_pattern_block(kind: str, rng, cfg: ModelConfig, dt):
+    """One block of a layer pattern: its pre-norm and its mixer."""
+    p = {"ln": layers.init_rmsnorm(cfg.d_model, dt)}
+    if kind == "M":
+        p["mamba"] = _init_mamba(rng, cfg, dt)
+    elif kind == "E":
+        p["moe"] = moe.init_expert_share(
+            rng, cfg.d_model, cfg.d_ff, cfg.n_experts,
+            cfg.experts_held or cfg.n_experts, cfg.shared_expert_ff, dt)
+    elif kind == "*":
+        p["attn"] = layers.init_attention(rng, cfg.d_model, cfg.n_heads,
+                                          cfg.n_kv_heads, cfg.head_dim,
+                                          False, dt)
+    else:
+        raise ValueError(f"unknown block kind {kind!r} in "
+                         f"{cfg.layer_pattern!r}")
+    return p
+
+
+def _pattern_block(kind: str, cfg: ModelConfig, p, x: Array):
+    """x + mixer(RMSNorm(x)) for one block; returns (x, the expert
+    layer's routed pairs per held expert, or None)."""
+    h = _norm(p["ln"], x, cfg.rms_eps)
+    pairs = None
+    if kind == "M":
+        y = _mamba(p["mamba"], h, cfg)
+    elif kind == "E":
+        y, pairs = moe.expert_share(p["moe"], h, top_k=cfg.n_experts_per_tok,
+                                    scaling=cfg.routed_scaling,
+                                    first=cfg.experts_first)
+    else:
+        y = tf.attention_mixer(p["attn"], cfg, h)
+    return pspec.constrain(x + y, "dp", None, None), pairs
+
+
 class Model:
     def __init__(self, cfg: ModelConfig):
         self.cfg = cfg
@@ -90,18 +140,18 @@ class Model:
         elif cfg.family == "ssm":
             p["layers"] = jax.vmap(lambda r: {
                 "ln": layers.init_rmsnorm(cfg.d_model, dt),
-                "mamba": ssm.init_mamba2(r, cfg.d_model, cfg.ssm_state,
-                                         cfg.ssm_head_dim, cfg.ssm_expand,
-                                         dtype=dt),
+                "mamba": _init_mamba(r, cfg, dt),
             })(jax.random.split(keys[2], cfg.n_layers))
         elif cfg.family == "hybrid":
             p["layers"] = jax.vmap(lambda r: {
                 "ln": layers.init_rmsnorm(cfg.d_model, dt),
-                "mamba": ssm.init_mamba2(r, cfg.d_model, cfg.ssm_state,
-                                         cfg.ssm_head_dim, cfg.ssm_expand,
-                                         dtype=dt),
+                "mamba": _init_mamba(r, cfg, dt),
             })(jax.random.split(keys[2], cfg.n_layers))
             p["shared"] = tf.init_block(keys[3], cfg, dt)
+        elif cfg.family == "pattern":
+            p["blocks"] = [_init_pattern_block(kind, r, cfg, dt) for kind, r
+                           in zip(cfg.layer_pattern, jax.random.split(
+                               keys[2], len(cfg.layer_pattern)))]
         elif cfg.family == "encdec":
             p["enc_layers"] = jax.vmap(
                 lambda r: tf.init_block(r, cfg, dt))(
@@ -170,11 +220,8 @@ class Model:
 
         def body(carry, p_l):
             h = carry
-            y = ssm.mamba2_forward(
-                p_l["mamba"], _norm(p_l["ln"], h, cfg.rms_eps),
-                d_state=cfg.ssm_state, head_dim=cfg.ssm_head_dim,
-                expand=cfg.ssm_expand, chunk=cfg.ssm_chunk,
-                return_state=want_state)
+            y = _mamba(p_l["mamba"], _norm(p_l["ln"], h, cfg.rms_eps), cfg,
+                       return_state=want_state)
             if want_state:
                 y, st = y
                 return pspec.constrain(h + y, "dp", None, None), st
@@ -202,11 +249,8 @@ class Model:
             h = carry
 
             def inner(hh, p_l):
-                y = ssm.mamba2_forward(
-                    p_l["mamba"], _norm(p_l["ln"], hh, cfg.rms_eps),
-                    d_state=cfg.ssm_state, head_dim=cfg.ssm_head_dim,
-                    expand=cfg.ssm_expand, chunk=cfg.ssm_chunk,
-                    return_state=want_kv)
+                y = _mamba(p_l["mamba"], _norm(p_l["ln"], hh, cfg.rms_eps),
+                           cfg, return_state=want_kv)
                 if want_kv:
                     y, st = y
                     return pspec.constrain(hh + y, "dp", None, None), \
@@ -222,6 +266,20 @@ class Model:
         x, (kvs, states) = jax.lax.scan(group_body, x, grouped,
                                         unroll=cfg.scan_unroll)
         return x, (kvs, states)
+
+    def _pattern_stack(self, params, x, remat: bool = True):
+        """Nemotron-H: the blocks of ``layer_pattern`` in order, each
+        rematerialized on its own. Returns (x, {"expert_pairs": (E
+        blocks, experts held) int32}) where the pattern has E blocks."""
+        cfg = self.cfg
+        pairs = []
+        for kind, p_b in zip(cfg.layer_pattern, params["blocks"]):
+            block = _maybe_remat(functools.partial(_pattern_block, kind, cfg),
+                                 remat)
+            x, n = block(p_b, x)
+            if n is not None:
+                pairs.append(n)
+        return x, ({"expert_pairs": jnp.stack(pairs)} if pairs else None)
 
     def _encoder(self, params, src: Array, remat: bool = True):
         cfg = self.cfg
@@ -258,7 +316,9 @@ class Model:
 
     def hidden_states(self, params, batch, want_cache: bool = False,
                       remat: bool = True):
-        """(hidden (B,S,D), aux, cache_parts) for train/prefill."""
+        """(hidden (B,S,D), aux, parts) for train/prefill: ``parts`` is
+        the decode cache's pieces with ``want_cache``; without, None, or
+        the layer-pattern stack's counters (``_pattern_stack``)."""
         cfg = self.cfg
         aux = jnp.zeros((), jnp.float32)
         cache_parts = None
@@ -275,6 +335,12 @@ class Model:
             pos = self._positions(batch, x.shape[1], x.shape[0])
             x, cache_parts = self._hybrid_stack(params, x, pos, want_cache,
                                                 remat)
+        elif cfg.family == "pattern":
+            if want_cache:
+                raise NotImplementedError(
+                    "the layer-pattern stack has no decode cache")
+            x = self._embed_in(params, batch)
+            x, cache_parts = self._pattern_stack(params, x, remat)
         elif cfg.family == "encdec":
             enc = self._encoder(params, batch["src_embeds"], remat)
             with jax.named_scope("model.embed"):
@@ -394,8 +460,7 @@ class Model:
                 y, st = ssm.mamba2_decode_step(
                     p_l["mamba"], _norm(p_l["ln"], h, cfg.rms_eps),
                     ssm.SSMState(conv=conv_l, ssm=st_l),
-                    d_state=cfg.ssm_state, head_dim=cfg.ssm_head_dim,
-                    expand=cfg.ssm_expand)
+                    d_state=cfg.ssm_state, head_dim=cfg.ssm_head_dim)
                 return h + y, (st.conv, st.ssm)
 
             x, (conv, st) = jax.lax.scan(
@@ -422,8 +487,7 @@ class Model:
                         p_l["mamba"],
                         _norm(p_l["ln"], hh, cfg.rms_eps),
                         ssm.SSMState(conv=c_l, ssm=s_l),
-                        d_state=cfg.ssm_state, head_dim=cfg.ssm_head_dim,
-                        expand=cfg.ssm_expand)
+                        d_state=cfg.ssm_state, head_dim=cfg.ssm_head_dim)
                     return hh + y, (st.conv, st.ssm)
 
                 h, (nc, ns) = jax.lax.scan(inner, h, (p_g, conv_l, st_l))

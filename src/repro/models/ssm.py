@@ -19,10 +19,12 @@ Array = jax.Array
 
 
 def init_mamba2(rng, d_model: int, d_state: int, head_dim: int = 64,
-                expand: int = 2, conv_width: int = 4, dtype=jnp.bfloat16):
-    d_inner = expand * d_model
+                expand: int = 2, conv_width: int = 4, dtype=jnp.bfloat16,
+                n_groups: int = 1, n_heads: int = 0):
+    """``n_heads`` > 0 sets d_inner = n_heads * head_dim (Nemotron-H);
+    otherwise d_inner = expand * d_model."""
+    d_inner = n_heads * head_dim if n_heads else expand * d_model
     n_heads = d_inner // head_dim
-    n_groups = 1
     k = jax.random.split(rng, 5)
     s = d_model ** -0.5
     d_conv = d_inner + 2 * n_groups * d_state
@@ -47,11 +49,25 @@ class SSMState(NamedTuple):
     ssm: Array    # (B, H, hd, N) recurrent state
 
 
-def _split(params, d_model: int, d_state: int, head_dim: int, expand: int):
-    d_inner = expand * d_model
-    n_heads = d_inner // head_dim
-    n_groups = 1
-    return d_inner, n_heads, n_groups
+def _split(params, head_dim: int):
+    """(d_inner, n_heads), read from the weights' shapes."""
+    d_inner = params["w_out"].shape[0]
+    return d_inner, d_inner // head_dim
+
+
+def _gated_norm(y: Array, z: Array, scale: Array, n_groups: int,
+                eps: float) -> Array:
+    """Mamba-2's gated RMSNorm: RMSNorm(y * silu(z)) over each of
+    ``n_groups`` equal groups of channels."""
+    g = y * jax.nn.silu(z)
+    g32 = g.astype(jnp.float32)
+    if n_groups > 1:
+        g32 = g32.reshape(*g32.shape[:-1], n_groups, -1)
+    var = jnp.mean(g32 * g32, axis=-1, keepdims=True)
+    g32 = g32 * jax.lax.rsqrt(var + eps)
+    if n_groups > 1:
+        g32 = g32.reshape(*y.shape)
+    return g32.astype(y.dtype) * scale
 
 
 def _causal_conv(xbc: Array, w: Array, b: Array) -> Array:
@@ -64,16 +80,17 @@ def _causal_conv(xbc: Array, w: Array, b: Array) -> Array:
 
 
 def mamba2_forward(params, x: Array, *, d_state: int, head_dim: int = 64,
-                   expand: int = 2, chunk: int = 256,
-                   return_state: bool = False):
+                   chunk: int = 256, n_groups: int = 1,
+                   norm_eps: float = 1e-6, return_state: bool = False):
     """x: (B, S, D) -> (y: (B, S, D)[, final SSMState]).
 
-    The device work is named by part: ``ssm.in_proj``, ``ssm.conv``,
-    ``ssm.ssd`` (discretization, the chunked scan and the skip),
-    ``ssm.gated_norm`` and ``ssm.out_proj``."""
+    Heads are split evenly over ``n_groups`` groups of B and C, and the
+    gated RMSNorm (epsilon ``norm_eps``) normalizes each group of
+    channels on its own. The device work is named by part:
+    ``ssm.in_proj``, ``ssm.conv``, ``ssm.ssd`` (discretization, the
+    chunked scan and the skip), ``ssm.gated_norm`` and ``ssm.out_proj``."""
     b, s, d_model = x.shape
-    d_inner, n_heads, n_groups = _split(params, d_model, d_state, head_dim,
-                                        expand)
+    d_inner, n_heads = _split(params, head_dim)
     with jax.named_scope("ssm.in_proj"):
         proj = x @ params["w_in"]
         z, xbc, dt = jnp.split(
@@ -96,12 +113,7 @@ def mamba2_forward(params, x: Array, *, d_state: int, head_dim: int = 64,
             * xh.astype(jnp.float32)
         y = y.reshape(b, s, d_inner).astype(x.dtype)
     with jax.named_scope("ssm.gated_norm"):
-        # gated RMSNorm (mamba2): norm(y * silu(z))
-        g = y * jax.nn.silu(z)
-        g32 = g.astype(jnp.float32)
-        var = jnp.mean(g32 * g32, axis=-1, keepdims=True)
-        g = (g32 * jax.lax.rsqrt(var + 1e-6)).astype(x.dtype) \
-            * params["norm_scale"]
+        g = _gated_norm(y, z, params["norm_scale"], n_groups, norm_eps)
     with jax.named_scope("ssm.out_proj"):
         out = g @ params["w_out"]
     if return_state:
@@ -116,7 +128,10 @@ def mamba2_forward(params, x: Array, *, d_state: int, head_dim: int = 64,
 
 def _ssd_chunked(xh, dt, a, bb, cc, chunk):
     """Chunked SSD. xh: (B,S,H,hd); dt: (B,S,H); a: (H,);
-    bb/cc: (B,S,G,N) with G=1. Returns (y (B,S,H,hd) f32, state (B,H,hd,N))."""
+    bb/cc: (B,S,G,N), head h reading group h // (H/G).
+    Returns (y (B,S,H,hd) f32, state (B,H,hd,N))."""
+    if bb.shape[2] > 1:
+        return _ssd_chunked_grouped(xh, dt, a, bb, cc, chunk)
     b, s, h, hd = xh.shape
     n = bb.shape[-1]
     q = chunk
@@ -172,12 +187,62 @@ def _ssd_chunked(xh, dt, a, bb, cc, chunk):
     return y, stf
 
 
+def _ssd_chunked_grouped(xh, dt, a, bb, cc, chunk):
+    """``_ssd_chunked`` for G > 1 groups of B and C: heads are split as
+    (G, H/G), so each group's C.B product is formed once for its heads."""
+    b, s, h, hd = xh.shape
+    g, n = bb.shape[2], bb.shape[3]
+    j = h // g
+    q = chunk
+    nc = -(-s // q)
+    pad = nc * q - s
+    if pad:
+        xh = jnp.pad(xh, ((0, 0), (0, pad), (0, 0), (0, 0)))
+        dt = jnp.pad(dt, ((0, 0), (0, pad), (0, 0)))
+        bb = jnp.pad(bb, ((0, 0), (0, pad), (0, 0), (0, 0)))
+        cc = jnp.pad(cc, ((0, 0), (0, pad), (0, 0), (0, 0)))
+    xc = xh.reshape(b, nc, q, g, j, hd).astype(jnp.float32)
+    dtc = dt.reshape(b, nc, q, g, j)
+    bc = bb.reshape(b, nc, q, g, n).astype(jnp.float32)
+    ccx = cc.reshape(b, nc, q, g, n).astype(jnp.float32)
+
+    cum = jnp.cumsum(dtc * a.reshape(g, j), axis=2)      # (B,nc,q,G,J)
+    total = cum[:, :, -1:]
+
+    idx = jnp.arange(q)
+    causal = idx[:, None] >= idx[None, :]
+    dec = jnp.exp(jnp.clip(cum[:, :, :, None] - cum[:, :, None],
+                           -60.0, 0.0))              # (B,nc,q,q,G,J)
+    cb = jnp.einsum("bcqgn,bcsgn->bcqsg", ccx, bc)   # (B,nc,q,q,G)
+    w_ = cb[..., None] * dec * dtc[:, :, None] \
+        * causal[None, None, :, :, None, None]
+    y_intra = jnp.einsum("bcqsgj,bcsgjd->bcqgjd", w_, xc)
+
+    decq = jnp.exp(jnp.clip(total - cum, -60.0, 0.0))  # (B,nc,q,G,J)
+    sin = jnp.einsum("bcqgj,bcqgjd,bcqgn->bcgjdn", decq * dtc, xc, bc)
+    chunk_decay = jnp.exp(jnp.clip(total[:, :, 0], -60.0, None))
+
+    def scan_fn(carry, inp):
+        sin_c, dec_c = inp
+        return carry * dec_c[..., None, None] + sin_c, carry
+
+    st0 = jnp.zeros((b, g, j, hd, n), jnp.float32)
+    stf, st_in = jax.lax.scan(
+        scan_fn, st0,
+        (jnp.moveaxis(sin, 1, 0), jnp.moveaxis(chunk_decay, 1, 0)))
+    st_in = jnp.moveaxis(st_in, 0, 1)                # (B,nc,G,J,hd,N)
+    y_inter = jnp.einsum("bcqgn,bcqgj,bcgjdn->bcqgjd",
+                         ccx, jnp.exp(jnp.clip(cum, -60.0, 0.0)), st_in)
+    y = (y_intra + y_inter).reshape(b, nc * q, h, hd)[:, :s]
+    return y, stf.reshape(b, h, hd, n)
+
+
 def mamba2_decode_step(params, x: Array, state: SSMState, *, d_state: int,
-                       head_dim: int = 64, expand: int = 2):
-    """Single-token recurrent step. x: (B, 1, D)."""
+                       head_dim: int = 64):
+    """Single-token recurrent step (one group of B and C). x: (B, 1, D)."""
     b, _, d_model = x.shape
-    d_inner, n_heads, n_groups = _split(params, d_model, d_state, head_dim,
-                                        expand)
+    d_inner, n_heads = _split(params, head_dim)
+    n_groups = 1
     proj = x @ params["w_in"]
     z, xbc_new, dt = jnp.split(
         proj, [d_inner, 2 * d_inner + 2 * n_groups * d_state], axis=-1)
@@ -202,9 +267,5 @@ def mamba2_decode_step(params, x: Array, state: SSMState, *, d_state: int,
     y = jnp.einsum("bhdn,bn->bhd", new_ssm, cvec) \
         + params["d_skip"][None, :, None] * xhh
     y = y.reshape(b, 1, d_inner).astype(x.dtype)
-    g = y * jax.nn.silu(z)
-    g32 = g.astype(jnp.float32)
-    var = jnp.mean(g32 * g32, axis=-1, keepdims=True)
-    g = (g32 * jax.lax.rsqrt(var + 1e-6)).astype(x.dtype) \
-        * params["norm_scale"]
+    g = _gated_norm(y, z, params["norm_scale"], n_groups, 1e-6)
     return g @ params["w_out"], SSMState(conv=new_conv, ssm=new_ssm)

@@ -120,6 +120,23 @@ def block_forward(p, cfg: ModelConfig, x: Array, positions: Array,
     return x, aux, ((k, v) if want_kv else None)
 
 
+def attention_mixer(p, cfg: ModelConfig, x: Array) -> Array:
+    """Nemotron-H's attention mixer: grouped-query causal softmax
+    attention with no positional encoding and no FFN after it. x (B, S,
+    D) is the block's normed input; the projections are named
+    ``attn.proj`` on the device, the attention itself ``attn.core``."""
+    b, s, _ = x.shape
+    with jax.named_scope("attn.proj"):
+        q, k, v = qkv_project(p, x, cfg.n_heads, cfg.n_kv_heads,
+                              cfg.head_dim)
+    with jax.named_scope("attn.core"):
+        o = flash_attention(q, k, v, causal=True,
+                            q_chunk=min(cfg.q_chunk, s),
+                            kv_chunk=min(cfg.kv_chunk, s))
+    with jax.named_scope("attn.proj"):
+        return o.reshape(b, s, -1) @ p["wo"]
+
+
 def prefill_cache_kv(cfg: ModelConfig, k: Array, v: Array):
     """Turn full-sequence (B,S,KH,hd) K/V into the cache layout: the last
     ``window`` entries rolled so slot == pos % window (SWA), or unchanged."""

@@ -44,13 +44,17 @@ def cox_loss(model: Model, params, batch):
     """Survival objective for trainer.make_train_step(objective='cox').
 
     The pooling and the partial likelihood are named ``cox.head`` on the
-    device."""
-    hidden, aux, _ = model.hidden_states(params, batch)
+    device. Counters the backbone keeps (the layer-pattern stack's routed
+    pairs) come back under ``counters``."""
+    hidden, aux, parts = model.hidden_states(params, batch)
     with jax.named_scope("cox.head"):
         eta = model.pooled_risk(params, hidden)
         loss = cox_partial_likelihood(eta.astype(jnp.float32),
                                       batch["time"], batch["event"])
-        return loss + 0.01 * aux, {"cox_nll": loss, "aux": aux}
+        metrics = {"cox_nll": loss, "aux": aux}
+        if parts is not None:
+            metrics["counters"] = parts
+        return loss + 0.01 * aux, metrics
 
 
 def pooled_features(model: Model, params, batch) -> Array:

@@ -75,6 +75,8 @@ def make_train_step(model: Model, tcfg: TrainConfig,
         new_params, new_opt, opt_metrics = opt_lib.adamw_update(
             grads, state.opt, state.params, tcfg)
         out = {"loss": loss, **opt_metrics}
+        if "counters" in metrics:
+            out["counters"] = metrics["counters"]
         return TrainState(params=new_params, opt=new_opt), out
 
     return train_step

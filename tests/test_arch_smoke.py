@@ -11,6 +11,9 @@ from repro.configs import REGISTRY, reduced_config
 from repro.models import build_model
 
 ARCHS = sorted(REGISTRY)
+# the layer-pattern stack (Nemotron-H) trains and scores whole sequences;
+# it has no token-decode cache
+DECODE_ARCHS = [a for a in ARCHS if REGISTRY[a].family != "pattern"]
 
 
 def make_batch(cfg, rng, bsz=2, seq=24, train=True):
@@ -62,7 +65,7 @@ def test_train_grads_finite(arch):
     assert any(float(jnp.abs(g).max()) > 0 for g in flat)
 
 
-@pytest.mark.parametrize("arch", ARCHS)
+@pytest.mark.parametrize("arch", DECODE_ARCHS)
 def test_prefill_and_decode_step(arch):
     cfg = reduced_config(REGISTRY[arch])
     model = build_model(cfg)

@@ -120,3 +120,43 @@ def test_coordinate_sweep_has_no_risk_set_gather(one_chip, chip_config):
     text = jax.jit(fit).lower(data).compile().as_text()
     assert 'op_name="' in text and "cd.stats/" in text
     assert not re.findall(r'op_name="[^"]*cd\.stats/gather', text)
+
+
+def test_nemotron_train_step_fits_one_v5e(one_chip, chip_config):
+    """The Cox train step of the nemotron3.train cell at its shapes (the
+    published blocks 0-6, 8 of 128 experts held, a vocabulary of 16,384
+    rows, 32 x 512 tokens, float32 weights and Adam moments): the chip's
+    compiler takes it, its device work is named, and the compiled
+    program's memory fits the chip's 16 GB."""
+    from repro.configs import TrainConfig, get_config
+    from repro.models import build_model
+    from repro.survival.head import init_cox_head
+    from repro.train.optimizer import init_opt_state
+    from repro.train.trainer import TrainState, make_train_step
+
+    cfg = get_config("nemotron-3-nano-30b-a3b")
+    cfg = cfg.scaled(n_layers=7, layer_pattern=cfg.layer_pattern[:7],
+                     experts_held=8, vocab_size=16384, dtype="float32")
+    model = build_model(cfg)
+
+    def init():
+        p = model.init_params(jax.random.PRNGKey(0))
+        p["cox_head"] = init_cox_head(jax.random.PRNGKey(1), cfg.d_model)
+        return TrainState(params=p, opt=init_opt_state(p))
+
+    place = lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,  # noqa: E731
+                                           sharding=one_chip)
+    state = jax.tree.map(place, jax.eval_shape(init))
+    batch = {"tokens": place(jax.ShapeDtypeStruct((32, 512), I32)),
+             "time": place(jax.ShapeDtypeStruct((32,), F32)),
+             "event": place(jax.ShapeDtypeStruct((32,), F32))}
+    step = jax.jit(make_train_step(model, TrainConfig(), objective="cox"),
+                   donate_argnums=(0,))
+    compiled = step.lower(state, batch).compile()
+    text = compiled.as_text()
+    for scope in ("moe.experts/", "moe.dispatch/", "attn.core/", "ssm.ssd/"):
+        assert scope in text, scope
+    mem = compiled.memory_analysis()
+    need = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+            - mem.alias_size_in_bytes + mem.temp_size_in_bytes)
+    assert need < 16e9, need
