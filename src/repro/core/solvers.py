@@ -37,6 +37,7 @@ import jax
 import jax.numpy as jnp
 
 from . import cox, surrogate
+from ..kernels import ops as kops
 from ..obs import solver as obs_solver
 
 Array = jax.Array
@@ -73,9 +74,9 @@ def _emit(telemetry, data, it, eta, beta, beta_prev, obj, lam2) -> None:
 # Coordinate descent (ours)
 # ---------------------------------------------------------------------------
 
-def _cd_sweep(data: cox.CoxData, eta: Array, beta: Array, l2c: Array,
-              l3c: Array, ev: Array, lam1, lam2, cubic: bool,
-              use_kernel: bool = False) -> Tuple[Array, Array]:
+def _cd_sweep_jnp(data: cox.CoxData, eta: Array, beta: Array, l2c: Array,
+                  l3c: Array, ev: Array, lam1, lam2, cubic: bool,
+                  use_kernel: bool = False) -> Tuple[Array, Array]:
     """One full sweep over all p coordinates (sequential, lax.fori_loop).
 
     ``ev`` is ``cox.risk_start_events(data)``, formed once per solve.
@@ -83,15 +84,12 @@ def _cd_sweep(data: cox.CoxData, eta: Array, beta: Array, l2c: Array,
     ``cd.update`` (the prox and the ``beta``/``eta`` update)."""
     xT = data.x.T  # (p, n)
 
-    if use_kernel:
-        from repro.kernels import ops as _kops
-
     def body(l, carry):
         eta, beta = carry
         xl = xT[l]
         if use_kernel:
             with jax.named_scope("cd.stats"):
-                g, h = _kops.cox_coord_grad_hess(eta, xl, data.delta)
+                g, h = kops.cox_coord_grad_hess(eta, xl, data.delta)
         else:
             g, h, _ = cox.coord_derivs(data, eta, xl, order=2, ev=ev)
         with jax.named_scope("cd.update"):
@@ -110,6 +108,41 @@ def _cd_sweep(data: cox.CoxData, eta: Array, beta: Array, l2c: Array,
     return jax.lax.fori_loop(0, data.p, body, (eta, beta))
 
 
+def _sweep_prep(data: cox.CoxData, l2c: Array, l3c: Array, ev: Array):
+    """The sweep kernel's inputs for one solve (the transposed, padded
+    design and the per-column constants), formed once, outside the loop
+    over sweeps; None where the kernel does not take this data
+    (``kernels.ops.cd_sweep_path`` counts which)."""
+    if kops.cd_sweep_path(data.x) != "kernel":
+        return None
+    return kops.cd_sweep_prepare(data.x, ev, data.delta, l2c, l3c)
+
+
+def _cd_sweep(data: cox.CoxData, eta: Array, beta: Array, l2c: Array,
+              l3c: Array, ev: Array, lam1, lam2, cubic: bool,
+              use_kernel: bool = False, prep=None) -> Tuple[Array, Array]:
+    """One full sweep over all p coordinates.
+
+    With ``prep`` (``_sweep_prep``'s) the sweep is lowered per platform:
+    on TPU one Pallas kernel runs the whole sweep (``kernels/cd_sweep.py``,
+    its device work named ``cd.stats``), elsewhere ``_cd_sweep_jnp``.
+    Without it, ``_cd_sweep_jnp`` everywhere."""
+    def jnp_sweep(eta, beta):
+        return _cd_sweep_jnp(data, eta, beta, l2c, l3c, ev, lam1, lam2,
+                             cubic, use_kernel)
+
+    if prep is None:
+        return jnp_sweep(eta, beta)
+
+    def kernel_sweep(eta, beta):
+        with jax.named_scope("cd.stats"):
+            return kops.cd_sweep(eta, beta, prep, lam1, lam2, cubic=cubic,
+                                 interpret=False)
+
+    return jax.lax.platform_dependent(eta, beta, tpu=kernel_sweep,
+                                      default=jnp_sweep)
+
+
 @partial(jax.jit, static_argnames=("n_iters", "method", "use_kernel",
                                    "telemetry"))
 def fit_cd(data: cox.CoxData, lam1: float = 0.0, lam2: float = 0.0,
@@ -118,20 +151,25 @@ def fit_cd(data: cox.CoxData, lam1: float = 0.0, lam2: float = 0.0,
            telemetry=None) -> FitResult:
     """FastSurvival coordinate descent (quadratic or cubic surrogate).
 
-    use_kernel=True routes the per-coordinate derivatives through the fused
-    Pallas kernel (kernels/cox_coord.py) — TPU fast path; requires tie-free
-    (strictly increasing) event times, see kernels/ops.py."""
+    By default each sweep runs as one Pallas kernel where the program is
+    lowered for TPU and the data takes it (float32, n within the kernel's
+    VMEM budget; ``kernels/cd_sweep.py``), else as jnp ops.
+    use_kernel=True instead routes each coordinate's derivatives through
+    the fused per-coordinate Pallas kernel (kernels/cox_coord.py), one call
+    per coordinate; it requires tie-free (strictly increasing) event
+    times, see kernels/ops.py."""
     cubic = method == "cd_cubic"
     beta = jnp.zeros(data.p, data.x.dtype) if beta0 is None else beta0
     eta = data.x @ beta
     l2c, l3c = cox.lipschitz_constants(data)
     ev = cox.risk_start_events(data)
+    prep = None if use_kernel else _sweep_prep(data, l2c, l3c, ev)
 
     def step(carry, it):
         eta, beta = carry
         beta_prev = beta
         eta, beta = _cd_sweep(data, eta, beta, l2c, l3c, ev, lam1, lam2,
-                              cubic, use_kernel=use_kernel)
+                              cubic, use_kernel=use_kernel, prep=prep)
         obj = _objective(data, eta, beta, lam1, lam2)
         _emit(telemetry, data, it, eta, beta, beta_prev, obj, lam2)
         return (eta, beta), obj
@@ -154,6 +192,7 @@ def fit_cd_tol(data: cox.CoxData, lam1: float = 0.0, lam2: float = 0.0,
     eta = data.x @ beta
     l2c, l3c = cox.lipschitz_constants(data)
     ev = cox.risk_start_events(data)
+    prep = _sweep_prep(data, l2c, l3c, ev)
     f0 = _objective(data, eta, beta, lam1, lam2)
 
     def cond(state):
@@ -164,7 +203,7 @@ def fit_cd_tol(data: cox.CoxData, lam1: float = 0.0, lam2: float = 0.0,
         eta, beta, _, cur, it = state
         beta_prev = beta
         eta, beta = _cd_sweep(data, eta, beta, l2c, l3c, ev, lam1, lam2,
-                              cubic)
+                              cubic, prep=prep)
         with jax.named_scope("cd.objective"):
             obj = _objective(data, eta, beta, lam1, lam2)
         _emit(telemetry, data, it, eta, beta, beta_prev, obj, lam2)

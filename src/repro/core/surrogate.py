@@ -95,9 +95,16 @@ def cubic_l1_prox(a: Array, b: Array, c: Array, d: Array, lam1: Array) -> Array:
                 cands.append(jnp.where(ok, root, 0.0))
     cands.append(jnp.zeros_like(a))      # kink at D = 0
     cands.append(-d)                     # kink at D = -d
-    cand = jnp.stack(cands)
-    vals = _cubic_piece_value(cand, a, b, c, lam1, d)
-    return cand[jnp.argmin(vals)]
+    # the first candidate of least value (argmin's choice), selected
+    # elementwise so that array arguments work as scalars do
+    best = cands[0]
+    best_val = _cubic_piece_value(best, a, b, c, lam1, d)
+    for cand in cands[1:]:
+        val = _cubic_piece_value(cand, a, b, c, lam1, d)
+        better = val < best_val
+        best = jnp.where(better, cand, best)
+        best_val = jnp.where(better, val, best_val)
+    return best
 
 
 def cubic_l1_prox_paper(a: Array, b: Array, c: Array, d: Array,

@@ -8,10 +8,13 @@ Selection logic:
     every dispatch looks up backend + kernel + power-of-two shape bucket
     in the JSON tune cache and falls back to the historical static
     defaults when the bucket is untuned. Pass an explicit block to pin;
-  * data with tied event times falls back to the pure-jnp Breslow
-    reference (the kernels implement the tie-free fast path; ties need a
-    gather at risk_start which is not worth a TPU kernel — see
-    kernels/cox_coord.py).
+  * the coordinate-sweep kernel (kernels/cd_sweep.py) takes tied data:
+    it weights each suffix sum by E, the events whose risk set starts
+    there (``core.cox.risk_start_events``), so it needs no gather at
+    risk_start. It runs for float32 data whose n fits its VMEM budget
+    (``cd_sweep_path`` says which path a sweep takes, and why);
+  * the other Cox kernels implement the tie-free fast path, and data with
+    tied event times falls back to the pure-jnp Breslow reference there.
 
 Telemetry: every dispatch increments ``kernel_dispatch_total`` labelled
 with the kernel name and block provenance (``tuned`` cache hit /
@@ -19,6 +22,8 @@ with the kernel name and block provenance (``tuned`` cache hit /
 dispatch-side: a kernel traced once inside an outer ``jit`` counts once
 per compilation, eager callers count per call — either way, a fleet
 silently running default blocks is visible in the metrics.
+``cd_sweep_path_total`` counts, the same way, which path each traced
+coordinate sweep took (``kernel`` or ``jnp``) and why.
 """
 from __future__ import annotations
 
@@ -28,7 +33,9 @@ import jax
 import jax.numpy as jnp
 
 from ..obs import metrics as obs_metrics
-from . import autotune, ref
+from . import autotune, cd_sweep as _cd_sweep, ref
+from .cd_sweep import cd_sweep  # noqa: F401  (one whole sweep, one kernel)
+from .cd_sweep import prepare as cd_sweep_prepare  # noqa: F401
 from .cox_batch import cox_batch as _cox_batch_kernel
 from .cox_coord import cox_coord as _cox_coord_kernel
 from .revcumsum import revcumsum as _revcumsum_kernel
@@ -39,6 +46,12 @@ from .survival_curves import (
 _M_DISPATCH = obs_metrics.REGISTRY.counter(
     "kernel_dispatch_total", "Pallas kernel dispatches by block provenance",
     ("kernel", "blocks"))
+
+_M_SWEEP = obs_metrics.REGISTRY.counter(
+    "cd_sweep_path_total",
+    "coordinate sweeps traced, by path: kernel (the one-kernel sweep, run "
+    "where the program is lowered for TPU) or jnp, and the reason",
+    ("path", "reason"))
 
 
 def _blocks(kernel: str, explicit: bool, **shape):
@@ -143,3 +156,20 @@ def lipschitz_constants(x: jax.Array, delta: jax.Array,
     if block_n is None:
         block_n = cfg["block_n"]
     return _lips_kernel(x, delta, block_n=block_n)
+
+
+def cd_sweep_path(x: jax.Array) -> str:
+    """``"kernel"`` where the one-kernel coordinate sweep takes a design
+    matrix ``x`` (n, p), else ``"jnp"``; counted under
+    ``cd_sweep_path_total`` with the reason: ``fits``, or ``dtype`` (not
+    float32: a bfloat16 fit keeps computing in bfloat16) or ``vmem`` (n
+    past the kernel's VMEM budget)."""
+    if x.dtype != jnp.float32:
+        path, reason = "jnp", "dtype"
+    elif not _cd_sweep.fits(x.shape[0]):
+        path, reason = "jnp", "vmem"
+    else:
+        path, reason = "kernel", "fits"
+    _M_SWEEP.inc(path=path, reason=reason)
+    return path
+

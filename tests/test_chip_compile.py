@@ -19,7 +19,7 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro.core import cox, solvers
-from repro.kernels import autotune
+from repro.kernels import autotune, cd_sweep
 from repro.kernels.cox_batch import cox_batch
 from repro.kernels.cox_coord import cox_coord
 from repro.kernels.lipschitz import lipschitz
@@ -69,6 +69,23 @@ def _compile(fn, one_chip, *shapes):
 
 F32, I32 = jnp.float32, jnp.int32
 N_COORD = 1 << 20
+N_SWEEP = P_SWEEP = 1200
+SWEEP_ROWS = cd_sweep.n_padded(N_SWEEP) // cd_sweep.LANES
+SWEEP_BLOCKS = -(-P_SWEEP // cd_sweep.BLOCK_COLS)
+
+
+def _cd_sweep(eta, beta, xt, ev, cols, lam1, lam2, cubic, interpret):
+    prep = dict(xt=xt, ev=ev, cols=cols)
+    return cd_sweep.cd_sweep(eta, beta, prep, lam1, lam2, cubic=cubic,
+                             interpret=interpret)
+
+
+_SWEEP_SHAPES = [((N_SWEEP,), F32), ((P_SWEEP,), F32),
+                 ((SWEEP_BLOCKS * cd_sweep.BLOCK_COLS, SWEEP_ROWS,
+                   cd_sweep.LANES), F32),
+                 ((SWEEP_ROWS, cd_sweep.LANES), F32),
+                 ((SWEEP_BLOCKS, 3, cd_sweep.BLOCK_COLS), F32),
+                 ((), F32), ((), F32)]
 
 # (kernel, default-config key, fixed kwargs, argument shapes)
 CASES = {
@@ -94,15 +111,16 @@ CASES = {
     "survival_curves_stratified": (
         survival_curves_stratified, "survival_curves_strat", {},
         [((1024,), F32), ((8, 2048), F32), ((1024,), I32)]),
+    "cd_sweep_quad": (_cd_sweep, None, {"cubic": False}, _SWEEP_SHAPES),
+    "cd_sweep_cubic": (_cd_sweep, None, {"cubic": True}, _SWEEP_SHAPES),
 }
 
 
 @pytest.mark.parametrize("case", sorted(CASES))
 def test_kernel_compiles_for_v5e(case, one_chip, chip_config):
     kernel, cfg_key, kwargs, shapes = CASES[case]
-    fn = functools.partial(kernel, **kwargs,
-                           **autotune.DEFAULT_CONFIGS[cfg_key],
-                           interpret=False)
+    blocks = autotune.DEFAULT_CONFIGS[cfg_key] if cfg_key else {}
+    fn = functools.partial(kernel, **kwargs, **blocks, interpret=False)
     compiled = _compile(fn, one_chip, *shapes)
     assert "tpu_custom_call" in compiled.as_text()
 
@@ -120,6 +138,43 @@ def test_coordinate_sweep_has_no_risk_set_gather(one_chip, chip_config):
     text = jax.jit(fit).lower(data).compile().as_text()
     assert 'op_name="' in text and "cd.stats/" in text
     assert not re.findall(r'op_name="[^"]*cd\.stats/gather', text)
+
+
+def _fit_hlo(one_chip, dtype):
+    """The compiled HLO text of the n = p = 1200 ``fit_cd_tol`` as the
+    benchmark's fit driver calls it, its data in ``dtype``."""
+    n = p = 1200
+    one = lambda s, dt: jax.ShapeDtypeStruct(s, dt, sharding=one_chip)
+    data = cox.CoxData(x=one((n, p), dtype), delta=one((n,), dtype),
+                       risk_start=one((n,), I32), tie_end=one((n,), I32))
+    fit = functools.partial(solvers.fit_cd_tol, lam1=1.0, lam2=1.0,
+                            max_iters=2000, tol=0.1, method="cd_quad")
+    return jax.jit(fit).lower(data).compile().as_text()
+
+
+def _op_names(text, pattern):
+    """The op_names of the instructions whose HLO line matches."""
+    return [m.group(1) if m else ""
+            for line in text.splitlines() if re.search(pattern, line)
+            for m in [re.search(r'op_name="([^"]*)"', line)]]
+
+
+def test_coordinate_sweep_is_one_kernel(one_chip, chip_config):
+    """On the chip each sweep of the n = p = 1200 fit is one Mosaic
+    kernel under ``cd.stats``: no XLA loop runs per coordinate (the one
+    while loop is the loop over sweeps) and no suffix sum runs as a
+    ``reduce-window`` there. bfloat16 data keeps the jnp sweep."""
+    text = _fit_hlo(one_chip, F32)
+    calls = _op_names(text, r"custom_call_target=\"tpu_custom_call\"")
+    assert len(calls) == 1 and "/cd.stats/" in calls[0], calls
+    loops = _op_names(text, r"= .*\bwhile\(")
+    assert len(loops) == 1 and loops[0].endswith("fit_cd_tol)/while"), loops
+    assert not [o for o in _op_names(text, r"\breduce-window\(")
+                if "cd.stats/" in o]
+
+    text = _fit_hlo(one_chip, jnp.bfloat16)
+    assert "tpu_custom_call" not in text
+    assert len(_op_names(text, r"= .*\bwhile\(")) == 2
 
 
 def test_nemotron_train_step_fits_one_v5e(one_chip, chip_config):

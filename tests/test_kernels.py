@@ -184,3 +184,97 @@ def test_ops_stratified_dispatch_matches_ref():
     want = ref.survival_curves_stratified_ref(eta, h0, strata)
     np.testing.assert_allclose(np.asarray(out), np.asarray(want),
                                rtol=1e-6, atol=1e-6)
+
+
+def _sweep_cohort(n, p, ties, seed):
+    """A time-sorted cohort; ``ties`` puts event times on a coarse grid."""
+    rng = np.random.default_rng(seed)
+    x = rng.standard_normal((n, p)).astype(np.float32)
+    t = (rng.integers(0, max(n // 5, 2), n) if ties
+         else rng.permutation(n)).astype(np.float32)
+    delta = (rng.uniform(size=n) < 0.6).astype(np.float32)
+    return cox.prepare(x, t, delta)
+
+
+@pytest.mark.parametrize("n,p,ties,method", [
+    (300, 13, False, "cd_quad"), (300, 13, True, "cd_cubic"),
+    (1100, 21, True, "cd_quad"), (1100, 8, False, "cd_cubic")])
+def test_cd_sweep_kernel_matches_jnp_sweep(n, p, ties, method):
+    """One kernel sweep (interpret mode) against the jnp sweep it
+    replaces on TPU: tied and tie-free cohorts, both surrogates, n and p
+    that fill no whole tile or column block."""
+    from repro.core import solvers
+
+    data = _sweep_cohort(n, p, ties, seed=n + p)
+    if ties:
+        assert len(np.unique(np.asarray(data.risk_start))) < n
+    l2c, l3c = cox.lipschitz_constants(data)
+    ev = cox.risk_start_events(data)
+    rng = np.random.default_rng(p)
+    beta = jnp.asarray(rng.standard_normal(p).astype(np.float32) * 0.2)
+    eta = data.x @ beta
+    cubic = method == "cd_cubic"
+    want = solvers._cd_sweep_jnp(data, eta, beta, l2c, l3c, ev, 0.5, 0.5,
+                                 cubic)
+    prep = ops.cd_sweep_prepare(data.x, ev, data.delta, l2c, l3c)
+    got = ops.cd_sweep(eta, beta, prep, 0.5, 0.5, cubic=cubic,
+                       interpret=True)
+    for g, w in zip(got, want):
+        g, w = np.asarray(g), np.asarray(w)
+        assert np.all(np.isfinite(g))
+        np.testing.assert_allclose(g, w, rtol=1e-5,
+                                   atol=1e-5 * np.max(np.abs(w)))
+
+
+def test_fit_cd_tol_through_sweep_kernel(monkeypatch):
+    """A whole early-stopping fit with its sweeps on the kernel (the TPU
+    branch, run in TPU interpret mode) stops after as many sweeps as the
+    jnp path and reaches its objective to 1e-6."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    from repro.core import solvers
+
+    data = _sweep_cohort(200, 12, True, seed=5)
+    kw = dict(lam1=0.5, lam2=0.5, max_iters=200, tol=1e-3,
+              method="cd_quad")
+    want = solvers.fit_cd_tol(data, **kw)
+    monkeypatch.setattr(jax.lax, "platform_dependent",
+                        lambda *a, tpu, default: tpu(*a))
+    fit = jax.jit(lambda d: solvers.fit_cd_tol.__wrapped__(d, **kw))
+    with pltpu.force_tpu_interpret_mode():
+        got = fit(data)
+    assert int(got.n_iters) == int(want.n_iters)
+    np.testing.assert_allclose(np.asarray(got.objective),
+                               np.asarray(want.objective), rtol=1e-6)
+    np.testing.assert_allclose(np.asarray(got.beta), np.asarray(want.beta),
+                               rtol=1e-4, atol=1e-5)
+
+
+def test_cd_sweep_path_counter():
+    """``cd_sweep_path_total`` counts the kernel where it engages, and
+    the jnp sweep with its reason for bfloat16 data and for an n past the
+    kernel's VMEM budget."""
+    from repro.core import solvers
+    from repro.kernels import cd_sweep
+    from repro.obs import metrics
+
+    counter = metrics.REGISTRY.counter("cd_sweep_path_total",
+                                       label_names=("path", "reason"))
+
+    def traced(n, p, dtype):
+        s = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt)  # noqa: E731
+        data = cox.CoxData(x=s((n, p), dtype), delta=s((n,), dtype),
+                           risk_start=s((n,), jnp.int32),
+                           tie_end=s((n,), jnp.int32))
+        jax.eval_shape(lambda d: solvers.fit_cd_tol.__wrapped__(
+            d, lam1=1.0, lam2=1.0, max_iters=5), data)
+
+    big = cd_sweep.TILE * 64 + 1
+    assert not cd_sweep.fits(big) and cd_sweep.fits(1200)
+    for (n, dtype), label in [
+            ((1200, jnp.float32), dict(path="kernel", reason="fits")),
+            ((1200, jnp.bfloat16), dict(path="jnp", reason="dtype")),
+            ((big, jnp.float32), dict(path="jnp", reason="vmem"))]:
+        before = counter.value(**label)
+        traced(n, 3, dtype)
+        assert counter.value(**label) == before + 1, label
