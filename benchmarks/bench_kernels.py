@@ -1,17 +1,14 @@
 """Kernel microbenchmarks (Cor. 3.3's O(n) machinery).
 
 On this container Pallas executes in interpret mode, so the `pallas_*`
-rows measure the correctness path, not TPU performance; the `xla_*` rows
-(same math through jnp/XLA-CPU) are the meaningful CPU timings and the
-scaling column (derived) demonstrates the O(n) claim."""
+rows measure the correctness path, not TPU performance."""
 import time
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core import cox
-from repro.kernels import ops, ref
+from repro.kernels import ops
 
 
 def _time(fn, *args, reps=5):
@@ -27,21 +24,6 @@ def _time(fn, *args, reps=5):
 def run():
     rows = []
     rng = np.random.default_rng(0)
-    ref_coord = jax.jit(ref.cox_coord_ref)
-    scaling = {}
-    for n in (10_000, 100_000, 1_000_000):
-        eta = jnp.asarray(rng.standard_normal(n) * 0.3, jnp.float32)
-        xl = jnp.asarray(rng.standard_normal(n), jnp.float32)
-        d = jnp.asarray((rng.uniform(size=n) < 0.7).astype(np.float32))
-        us = _time(ref_coord, eta, xl, d)
-        scaling[n] = us
-        rows.append((f"kernels/xla_cox_coord/n={n}", us,
-                     f"per_sample_ns={us * 1e3 / n:.2f}"))
-    # O(n) check: 100x n -> ~100x time (not n^2's 10000x)
-    ratio = scaling[1_000_000] / scaling[10_000]
-    rows.append(("kernels/xla_cox_coord/linearity", 0.0,
-                 f"t(1M)/t(10k)={ratio:.0f} (O(n) ~ 100)"))
-
     n, p = 100_000, 64
     x = jnp.asarray(rng.standard_normal((n, p)), jnp.float32)
     eta = jnp.asarray(rng.standard_normal(n) * 0.3, jnp.float32)
@@ -53,17 +35,6 @@ def run():
     v = jnp.asarray(rng.standard_normal((n, 128)), jnp.float32)
     rows.append((f"kernels/pallas_revcumsum_interp/n={n},m=128",
                  _time(ops.revcumsum, v, reps=2), "interpret-mode"))
-    coord = jax.jit(lambda e, xx, dd: ops.cox_coord_grad_hess(e, xx, dd))
-    eta1 = jnp.asarray(rng.standard_normal(n) * 0.3, jnp.float32)
-    x1 = jnp.asarray(rng.standard_normal(n), jnp.float32)
-    d1 = jnp.asarray((rng.uniform(size=n) < 0.7).astype(np.float32))
-    rows.append((f"kernels/pallas_cox_coord_interp/n={n}",
-                 _time(coord, eta1, x1, d1, reps=2), "interpret-mode"))
-    m = 16
-    xl = jnp.asarray(rng.standard_normal((n, m)), jnp.float32)
-    rows.append((f"kernels/pallas_lipschitz_interp/n={n},m={m}",
-                 _time(ops.lipschitz_constants, xl, d1, reps=2),
-                 "interpret-mode"))
     b, g = 1024, 128
     etac = jnp.asarray(rng.standard_normal(b) * 0.5, jnp.float32)
     h0 = jnp.asarray(np.linspace(0.0, 2.0, g), jnp.float32)

@@ -7,8 +7,8 @@ With one chip, four phases run in this one process, on seeded data:
 
 * ``fit``    -- ``solvers.fit_cd`` and ``beam.beam_search(k=15)`` on the
   paper's Appendix-C generator at the ``SyntheticSpec`` defaults, then
-  ``fit_cd`` through the fused ``cox_coord`` kernel against the jnp path
-  on tie-free data;
+  ``fit_cd`` on the chip (each sweep one ``cd_sweep`` kernel) against the
+  same call on the CPU device (the jnp sweep);
 * ``stream`` -- ``solvers.fit_stream`` (global mode) over 1 GiB of f32
   features held on the device in chunks, through the ``revcumsum`` kernel;
 * ``serve``  -- the fitted model, and an 8-stratum variant, rolled out
@@ -98,13 +98,17 @@ class CompileClock:
 
 
 def kernel_dispatches() -> dict:
-    """``kernel_dispatch_total`` summed over block provenance, per kernel."""
+    """``kernel_dispatch_total`` summed over block provenance, per kernel,
+    and as ``cd_sweep`` the coordinate sweeps traced onto the one-kernel
+    path (``cd_sweep_path_total{path="kernel"}``)."""
     from repro.kernels import autotune, ops
 
     counter = ops._M_DISPATCH
-    return {k: sum(counter.value(kernel=k, blocks=b)
-                   for b in ("tuned", "default", "explicit"))
-            for k in autotune.DEFAULT_CONFIGS}
+    out = {k: sum(counter.value(kernel=k, blocks=b)
+                  for b in ("tuned", "default", "explicit"))
+           for k in autotune.DEFAULT_CONFIGS}
+    out["cd_sweep"] = ops._M_SWEEP.value(path="kernel", reason="fits")
+    return out
 
 
 def check(cond: bool, what: str) -> None:
@@ -191,7 +195,6 @@ def serve_through_registry(model, x, strata, model_id: str,
 
 def phase_fit(sz: Sizes, state: dict) -> dict:
     import jax
-    import jax.numpy as jnp
     import numpy as np
 
     from repro.core import beam, cox, solvers
@@ -219,15 +222,16 @@ def phase_fit(sz: Sizes, state: dict) -> dict:
     truth = set(np.flatnonzero(beta_star).tolist())
     found = set(br.supports[-1].tolist())
 
-    # the fused kernel against the jnp path, on tie-free data (each
-    # sample its own time, in sorted order)
-    idx = jnp.arange(data.n, dtype=jnp.int32)
-    tie_free = cox.CoxData(x=data.x, delta=data.delta, risk_start=idx,
-                           tie_end=idx)
-    fits = {k: solvers.fit_cd(tie_free, lam2=lam2, n_iters=sz.kernel_iters,
-                              use_kernel=k) for k in (True, False)}
-    b_k, b_j = (np.asarray(fits[k].beta) for k in (True, False))
-    o_k, o_j = (np.asarray(fits[k].objective) for k in (True, False))
+    # the sweep kernel against the jnp sweep: the same fit, lowered for
+    # the default device and for the CPU
+    cpu = jax.devices("cpu")[0]
+    fits = {"kernel": solvers.fit_cd(data, lam2=lam2,
+                                     n_iters=sz.kernel_iters)}
+    with jax.default_device(cpu):
+        fits["jnp"] = solvers.fit_cd(jax.device_put(data, cpu), lam2=lam2,
+                                     n_iters=sz.kernel_iters)
+    b_k, b_j = (np.asarray(fits[k].beta) for k in ("kernel", "jnp"))
+    o_k, o_j = (np.asarray(fits[k].objective) for k in ("kernel", "jnp"))
     beta_diff = float(np.max(np.abs(b_k - b_j)))
     np.testing.assert_allclose(o_k, o_j, rtol=1e-5)
     np.testing.assert_allclose(b_k, b_j, rtol=1e-3, atol=1e-4)
@@ -422,7 +426,7 @@ def phase_multichip(sz: Sizes, state: dict) -> dict:
 # -- runner ------------------------------------------------------------------
 
 EXPECTED_KERNELS = {
-    "fit": ("cox_coord",),
+    "fit": ("cd_sweep",),
     "stream": ("revcumsum",),
     "serve": ("survival_curves", "survival_curves_strat"),
     "deep": ("survival_curves",),

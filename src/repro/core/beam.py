@@ -12,7 +12,8 @@ jitted:
     1-D surrogate steps on that coordinate alone (vmapped over p) and
     measure the *actual* loss decrease — the paper's selection rule
     ("which coefficient, if optimized, results in the largest decrease").
-  * ``finetune``: CD sweeps over the (padded) support columns to tolerance.
+  * ``finetune``: CD sweeps over the (padded) support columns, through
+    the same jnp sweep as ``solvers.fit_cd`` (``solvers.coord_sweep``).
 """
 from __future__ import annotations
 
@@ -24,7 +25,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from . import cox, surrogate
+from . import cox, solvers, surrogate
 from ..obs import trace
 
 Array = jax.Array
@@ -79,21 +80,15 @@ def finetune(data: cox.CoxData, support_idx: Array, support_mask: Array,
         cox.CoxData(x=cols, delta=data.delta, risk_start=data.risk_start,
                     tie_end=data.tie_end))
     ev = cox.risk_start_events(data)
+    cols_t = cols.T
+
+    def coord_step(j, g, h, bj):
+        step = surrogate.quad_min(g + 2.0 * lam2 * bj, l2c[j] + 2.0 * lam2)
+        return jnp.where(support_mask[j] > 0, step, 0.0)
 
     def sweep(carry, _):
-        eta, beta_s = carry
-
-        def body(j, c):
-            eta, beta_s = c
-            xl = cols[:, j]
-            g, _, _ = cox.coord_derivs(data, eta, xl, order=2, ev=ev)
-            step = surrogate.quad_min(g + 2.0 * lam2 * beta_s[j],
-                                      l2c[j] + 2.0 * lam2)
-            step = jnp.where(support_mask[j] > 0, step, 0.0)
-            return eta + step * xl, beta_s.at[j].add(step)
-
-        eta, beta_s = jax.lax.fori_loop(0, k_max, body, (eta, beta_s))
-        return (eta, beta_s), None
+        return solvers.coord_sweep(data, *carry, cols_t, ev,
+                                   coord_step), None
 
     eta0 = jnp.zeros(data.n, cols.dtype)
     beta0 = jnp.zeros(k_max, cols.dtype)

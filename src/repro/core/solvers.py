@@ -74,38 +74,29 @@ def _emit(telemetry, data, it, eta, beta, beta_prev, obj, lam2) -> None:
 # Coordinate descent (ours)
 # ---------------------------------------------------------------------------
 
-def _cd_sweep_jnp(data: cox.CoxData, eta: Array, beta: Array, l2c: Array,
-                  l3c: Array, ev: Array, lam1, lam2, cubic: bool,
-                  use_kernel: bool = False) -> Tuple[Array, Array]:
-    """One full sweep over all p coordinates (sequential, lax.fori_loop).
+def coord_sweep(data: cox.CoxData, eta: Array, beta: Array, cols_t: Array,
+                ev: Array, coord_step) -> Tuple[Array, Array]:
+    """One coordinate-descent sweep as jnp ops: the columns ``cols_t``
+    (p', n) visited in order (a sequential ``lax.fori_loop``). Every jnp
+    CD loop over the Cox surrogate runs this body.
 
-    ``ev`` is ``cox.risk_start_events(data)``, formed once per solve.
-    The device work is named: ``cd.stats`` (``cox.coord_derivs``) and
-    ``cd.update`` (the prox and the ``beta``/``eta`` update)."""
-    xT = data.x.T  # (p, n)
-
+    A visit takes (g, h) of the column from ``cox.coord_derivs`` (named
+    ``cd.stats``); then, named ``cd.update``, ``step = coord_step(l, g,
+    h, beta[l])``, ``beta[l] += step`` and ``eta += step * x_l``.
+    ``coord_step`` is the caller's prox; ``ev`` is
+    ``cox.risk_start_events(data)``, formed once per solve. Returns the
+    new (eta, beta)."""
     def body(l, carry):
         eta, beta = carry
-        xl = xT[l]
-        if use_kernel:
-            with jax.named_scope("cd.stats"):
-                g, h = kops.cox_coord_grad_hess(eta, xl, data.delta)
-        else:
-            g, h, _ = cox.coord_derivs(data, eta, xl, order=2, ev=ev)
+        xl = cols_t[l]
+        g, h, _ = cox.coord_derivs(data, eta, xl, order=2, ev=ev)
         with jax.named_scope("cd.update"):
-            bl = beta[l]
-            a = g + 2.0 * lam2 * bl
-            if cubic:
-                step = surrogate.cubic_l1_prox(
-                    a, h + 2.0 * lam2, l3c[l], bl, lam1)
-            else:
-                step = surrogate.quad_l1_prox(a, l2c[l] + 2.0 * lam2, bl,
-                                              lam1)
+            step = coord_step(l, g, h, beta[l])
             beta = beta.at[l].add(step)
             eta = eta + step * xl
         return eta, beta
 
-    return jax.lax.fori_loop(0, data.p, body, (eta, beta))
+    return jax.lax.fori_loop(0, cols_t.shape[0], body, (eta, beta))
 
 
 def _sweep_prep(data: cox.CoxData, l2c: Array, l3c: Array, ev: Array):
@@ -120,16 +111,23 @@ def _sweep_prep(data: cox.CoxData, l2c: Array, l3c: Array, ev: Array):
 
 def _cd_sweep(data: cox.CoxData, eta: Array, beta: Array, l2c: Array,
               l3c: Array, ev: Array, lam1, lam2, cubic: bool,
-              use_kernel: bool = False, prep=None) -> Tuple[Array, Array]:
-    """One full sweep over all p coordinates.
+              prep=None) -> Tuple[Array, Array]:
+    """One full sweep of ``fit_cd``/``fit_cd_tol`` over all p coordinates,
+    at the quadratic (``l2c``) or cubic (``l3c``) surrogate's l1 prox.
 
     With ``prep`` (``_sweep_prep``'s) the sweep is lowered per platform:
     on TPU one Pallas kernel runs the whole sweep (``kernels/cd_sweep.py``,
-    its device work named ``cd.stats``), elsewhere ``_cd_sweep_jnp``.
-    Without it, ``_cd_sweep_jnp`` everywhere."""
+    its device work named ``cd.stats``), elsewhere ``coord_sweep``.
+    Without it, ``coord_sweep`` everywhere."""
+    def coord_step(l, g, h, bl):
+        a = g + 2.0 * lam2 * bl
+        if cubic:
+            return surrogate.cubic_l1_prox(a, h + 2.0 * lam2, l3c[l], bl,
+                                           lam1)
+        return surrogate.quad_l1_prox(a, l2c[l] + 2.0 * lam2, bl, lam1)
+
     def jnp_sweep(eta, beta):
-        return _cd_sweep_jnp(data, eta, beta, l2c, l3c, ev, lam1, lam2,
-                             cubic, use_kernel)
+        return coord_sweep(data, eta, beta, data.x.T, ev, coord_step)
 
     if prep is None:
         return jnp_sweep(eta, beta)
@@ -143,38 +141,49 @@ def _cd_sweep(data: cox.CoxData, eta: Array, beta: Array, l2c: Array,
                                       default=jnp_sweep)
 
 
-@partial(jax.jit, static_argnames=("n_iters", "method", "use_kernel",
-                                   "telemetry"))
-def fit_cd(data: cox.CoxData, lam1: float = 0.0, lam2: float = 0.0,
-           n_iters: int = 100, beta0: Optional[Array] = None,
-           method: str = "cd_quad", use_kernel: bool = False,
-           telemetry=None) -> FitResult:
-    """FastSurvival coordinate descent (quadratic or cubic surrogate).
-
-    By default each sweep runs as one Pallas kernel where the program is
-    lowered for TPU and the data takes it (float32, n within the kernel's
-    VMEM budget; ``kernels/cd_sweep.py``), else as jnp ops.
-    use_kernel=True instead routes each coordinate's derivatives through
-    the fused per-coordinate Pallas kernel (kernels/cox_coord.py), one call
-    per coordinate; it requires tie-free (strictly increasing) event
-    times, see kernels/ops.py."""
+def _cd_solve(data: cox.CoxData, lam1, lam2, beta0, method: str,
+              telemetry):
+    """What ``fit_cd`` and ``fit_cd_tol`` share: the start (eta, beta),
+    with the per-solve constants formed once, and ``step(eta, beta, it)
+    -> (eta, beta, objective)``, which runs one sweep, the objective
+    (named ``cd.objective``) and the telemetry callback."""
     cubic = method == "cd_cubic"
     beta = jnp.zeros(data.p, data.x.dtype) if beta0 is None else beta0
     eta = data.x @ beta
     l2c, l3c = cox.lipschitz_constants(data)
     ev = cox.risk_start_events(data)
-    prep = None if use_kernel else _sweep_prep(data, l2c, l3c, ev)
+    prep = _sweep_prep(data, l2c, l3c, ev)
 
-    def step(carry, it):
-        eta, beta = carry
+    def step(eta, beta, it):
         beta_prev = beta
         eta, beta = _cd_sweep(data, eta, beta, l2c, l3c, ev, lam1, lam2,
-                              cubic, use_kernel=use_kernel, prep=prep)
-        obj = _objective(data, eta, beta, lam1, lam2)
+                              cubic, prep=prep)
+        with jax.named_scope("cd.objective"):
+            obj = _objective(data, eta, beta, lam1, lam2)
         _emit(telemetry, data, it, eta, beta, beta_prev, obj, lam2)
+        return eta, beta, obj
+
+    return eta, beta, step
+
+
+@partial(jax.jit, static_argnames=("n_iters", "method", "telemetry"))
+def fit_cd(data: cox.CoxData, lam1: float = 0.0, lam2: float = 0.0,
+           n_iters: int = 100, beta0: Optional[Array] = None,
+           method: str = "cd_quad", telemetry=None) -> FitResult:
+    """FastSurvival coordinate descent (quadratic or cubic surrogate),
+    ``n_iters`` sweeps over all p coordinates.
+
+    Each sweep runs as one Pallas kernel where the program is lowered for
+    TPU and the data takes it (float32, n within the kernel's VMEM budget;
+    ``kernels/cd_sweep.py``), else as the jnp ``coord_sweep``. Both take
+    tied event times."""
+    eta, beta, step = _cd_solve(data, lam1, lam2, beta0, method, telemetry)
+
+    def scan_step(carry, it):
+        eta, beta, obj = step(*carry, it)
         return (eta, beta), obj
 
-    (eta, beta), obj = jax.lax.scan(step, (eta, beta),
+    (eta, beta), obj = jax.lax.scan(scan_step, (eta, beta),
                                     jnp.arange(n_iters))
     return FitResult(beta=beta, objective=obj, n_iters=jnp.int32(n_iters))
 
@@ -187,12 +196,7 @@ def fit_cd_tol(data: cox.CoxData, lam1: float = 0.0, lam2: float = 0.0,
     """Early-stopping variant (while_loop): stops when the objective
     decrease over one sweep falls below ``tol`` (monotonicity is guaranteed
     by the surrogate majorization, so this is a sound criterion)."""
-    cubic = method == "cd_cubic"
-    beta = jnp.zeros(data.p, data.x.dtype) if beta0 is None else beta0
-    eta = data.x @ beta
-    l2c, l3c = cox.lipschitz_constants(data)
-    ev = cox.risk_start_events(data)
-    prep = _sweep_prep(data, l2c, l3c, ev)
+    eta, beta, step = _cd_solve(data, lam1, lam2, beta0, method, telemetry)
     f0 = _objective(data, eta, beta, lam1, lam2)
 
     def cond(state):
@@ -201,12 +205,7 @@ def fit_cd_tol(data: cox.CoxData, lam1: float = 0.0, lam2: float = 0.0,
 
     def body(state):
         eta, beta, _, cur, it = state
-        beta_prev = beta
-        eta, beta = _cd_sweep(data, eta, beta, l2c, l3c, ev, lam1, lam2,
-                              cubic, prep=prep)
-        with jax.named_scope("cd.objective"):
-            obj = _objective(data, eta, beta, lam1, lam2)
-        _emit(telemetry, data, it, eta, beta, beta_prev, obj, lam2)
+        eta, beta, obj = step(eta, beta, it)
         return eta, beta, cur, obj, it + 1
 
     state = (eta, beta, f0 + 2.0 * tol + 1.0, f0, jnp.int32(0))
@@ -221,8 +220,7 @@ def fit_cd_tol(data: cox.CoxData, lam1: float = 0.0, lam2: float = 0.0,
 def fit_stream(source, lam1: float = 0.0, lam2: float = 0.0,
                n_epochs: int = 200, tol: float = 0.0,
                mode: str = "global", beta0: Optional[Array] = None,
-               telemetry=None, use_kernel: Optional[bool] = None,
-               max_backtracks: int = 30) -> FitResult:
+               telemetry=None, max_backtracks: int = 30) -> FitResult:
     """Streaming proximal diagonal-Newton fit over a chunk source.
 
     ``source`` is any indexable of ``streaming.Chunk``s (``len`` +
@@ -254,11 +252,8 @@ def fit_stream(source, lam1: float = 0.0, lam2: float = 0.0,
         grad_hess = streaming.streaming_grad_hess
         loss_fn = streaming.streaming_loss
     elif mode == "chunk":
-        def grad_hess(src, b, use_kernel=None):
-            return streaming.stratified_grad_hess(src, b, use_kernel)
-
-        def loss_fn(src, b, use_kernel=None):
-            return streaming.stratified_loss(src, b)
+        grad_hess = streaming.stratified_grad_hess
+        loss_fn = streaming.stratified_loss
     else:
         raise ValueError(f"unknown mode: {mode!r}")
 
@@ -270,7 +265,7 @@ def fit_stream(source, lam1: float = 0.0, lam2: float = 0.0,
     step_scale = 1.0
     it = 0
     for it in range(n_epochs):
-        g_s, h_s, _ = grad_hess(source, beta, use_kernel=use_kernel)
+        g_s, h_s, _ = grad_hess(source, beta)
         g = g_s + 2.0 * lam2 * beta
         h = jnp.maximum(h_s + 2.0 * lam2, 1e-12)
         cand, new_obj = beta, obj
@@ -469,17 +464,12 @@ def fit_cd_penalized(data: cox.CoxData, penalty: str = "scad",
     ev = cox.risk_start_events(data)
     xT = data.x.T
 
+    def coord_step(l, g, h, bl):
+        a = g + 2.0 * lam2 * bl
+        return prox(a, l2c[l] + 2.0 * lam2, bl, lam1, gamma)
+
     def sweep(carry, _):
-        eta, beta = carry
-
-        def body(l, c):
-            eta, beta = c
-            g, _, _ = cox.coord_derivs(data, eta, xT[l], order=2, ev=ev)
-            a = g + 2.0 * lam2 * beta[l]
-            step = prox(a, l2c[l] + 2.0 * lam2, beta[l], lam1, gamma)
-            return eta + step * xT[l], beta.at[l].add(step)
-
-        eta, beta = jax.lax.fori_loop(0, data.p, body, (eta, beta))
+        eta, beta = coord_sweep(data, *carry, xT, ev, coord_step)
         obj = cox.loss_from_eta(data, eta) + lam2 * jnp.sum(beta * beta) \
             + pval(beta, lam1, gamma)
         return (eta, beta), obj

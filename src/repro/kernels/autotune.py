@@ -1,8 +1,8 @@
 """Block-size autotuner for the Pallas kernels.
 
-Per kernel (``revcumsum``, ``cox_coord``, ``cox_batch``, ``lipschitz``,
-``survival_curves``) and per shape bucket (power-of-two buckets on the
-kernel's shape axes, matching the serving engine's batch bucketing),
+Per kernel (``revcumsum``, ``cox_batch``, ``survival_curves``) and per
+shape bucket (power-of-two buckets on the kernel's shape axes, matching
+the serving engine's batch bucketing),
 ``autotune()`` times a small candidate grid of block configs with
 ``block_until_ready``, picks the winner, and persists it to a JSON cache
 keyed by ``backend/kernel/bucket``. ``ops.py`` calls ``lookup()`` on every
@@ -28,8 +28,6 @@ import numpy as np
 
 from ..obs import events as obs_events
 from .cox_batch import cox_batch
-from .cox_coord import cox_coord
-from .lipschitz import lipschitz
 from .revcumsum import revcumsum
 from .survival_curves import survival_curves, survival_curves_stratified
 
@@ -43,9 +41,7 @@ CACHE_VERSION = 1
 # untuned deployments behave exactly as before
 DEFAULT_CONFIGS: Dict[str, Dict[str, int]] = {
     "revcumsum": {"block_n": 512},
-    "cox_coord": {"block": 1024},
     "cox_batch": {"block_n": 512, "block_p": 256},
-    "lipschitz": {"block_n": 512},
     "survival_curves": {"block_b": 256, "block_g": 128},
     "survival_curves_strat": {"block_g": 128},
 }
@@ -53,9 +49,7 @@ DEFAULT_CONFIGS: Dict[str, Dict[str, int]] = {
 # shape axes that key a bucket, in display order
 SHAPE_AXES: Dict[str, Tuple[str, ...]] = {
     "revcumsum": ("n", "m"),
-    "cox_coord": ("n",),
     "cox_batch": ("n", "p"),
-    "lipschitz": ("n", "m"),
     "survival_curves": ("b", "g"),
     "survival_curves_strat": ("b", "g"),
 }
@@ -64,9 +58,7 @@ SHAPE_AXES: Dict[str, Tuple[str, ...]] = {
 # grossly oversized for a bucket; the default config always survives)
 BLOCK_AXES: Dict[str, Dict[str, str]] = {
     "revcumsum": {"block_n": "n"},
-    "cox_coord": {"block": "n"},
     "cox_batch": {"block_n": "n", "block_p": "p"},
-    "lipschitz": {"block_n": "n"},
     "survival_curves": {"block_b": "b", "block_g": "g"},
     "survival_curves_strat": {"block_g": "g"},
 }
@@ -75,14 +67,12 @@ BLOCK_AXES: Dict[str, Dict[str, str]] = {
 # size) and all TPU-tileable (multiples of the (8, 128) f32 tile)
 CANDIDATES: Dict[str, List[Dict[str, int]]] = {
     "revcumsum": [{"block_n": b} for b in (256, 512, 1024, 2048)],
-    "cox_coord": [{"block": b} for b in (1024, 2048, 4096, 8192)],
     "cox_batch": [
         {"block_n": 512, "block_p": 256},
         {"block_n": 1024, "block_p": 256},
         {"block_n": 2048, "block_p": 128},
         {"block_n": 1024, "block_p": 512},
     ],
-    "lipschitz": [{"block_n": b} for b in (256, 512, 1024, 2048)],
     "survival_curves": [
         {"block_b": 128, "block_g": 128},
         {"block_b": 256, "block_g": 128},
@@ -98,18 +88,14 @@ CANDIDATES: Dict[str, List[Dict[str, int]]] = {
 # shapes plus the default serving curve shapes (engine grid_size=128)
 DEFAULT_SWEEP: List[Tuple[str, Dict[str, int]]] = [
     ("revcumsum", {"n": 65536, "m": 128}),
-    ("cox_coord", {"n": 65536}),
     ("cox_batch", {"n": 100_000, "p": 64}),
-    ("lipschitz", {"n": 65536, "m": 16}),
     ("survival_curves", {"b": 256, "g": 128}),
     ("survival_curves", {"b": 1024, "g": 128}),
 ]
 
 _KERNEL_FNS = {
     "revcumsum": revcumsum,
-    "cox_coord": cox_coord,
     "cox_batch": cox_batch,
-    "lipschitz": lipschitz,
     "survival_curves": survival_curves,
     "survival_curves_strat": survival_curves_stratified,
 }
@@ -196,11 +182,6 @@ def _build_inputs(kernel: str, shape: Dict[str, int], seed: int = 0):
     if kernel == "revcumsum":
         n, m = shape["n"], shape["m"]
         return (jnp.asarray(rng.standard_normal((n, m)), jnp.float32),)
-    if kernel == "cox_coord":
-        n = shape["n"]
-        return (jnp.asarray(rng.standard_normal(n) * 0.3, jnp.float32),
-                jnp.asarray(rng.standard_normal(n), jnp.float32),
-                jnp.asarray((rng.uniform(size=n) < 0.7).astype(np.float32)))
     if kernel == "cox_batch":
         n, p = shape["n"], shape["p"]
         x = jnp.asarray(rng.standard_normal((n, p)), jnp.float32)
@@ -210,10 +191,6 @@ def _build_inputs(kernel: str, shape: Dict[str, int], seed: int = 0):
         inv_s0 = 1.0 / jax.lax.cumsum(w, axis=0, reverse=True)
         wa = w * jnp.cumsum(d * inv_s0)
         return (x, w, wa - d, wa, d, inv_s0)
-    if kernel == "lipschitz":
-        n, m = shape["n"], shape["m"]
-        return (jnp.asarray(rng.standard_normal((n, m)), jnp.float32),
-                jnp.asarray((rng.uniform(size=n) < 0.7).astype(np.float32)))
     if kernel == "survival_curves":
         b, g = shape["b"], shape["g"]
         return (jnp.asarray(rng.standard_normal(b) * 0.5, jnp.float32),

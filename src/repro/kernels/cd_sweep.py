@@ -31,11 +31,11 @@ scalars (``beta``, the Lipschitz constants, ``sum delta x``) sit in SMEM;
 the kernel returns each coordinate's step and the caller adds it to
 ``beta``, which is the same float32 addition the jnp sweep makes.
 
-Suffix sums, all in float32: the triangular-ones matmuls of
-``kernels/cox_coord.py`` at ``Precision.HIGHEST``, the moments in one
-operand (two for the quadratic surrogate, which takes no h). On a v5e
-this ran a solve of the paper's n = p = 1200 fit 1.8x faster than
-log-step roll-and-add suffix sums on the vector unit.
+Suffix sums, all in float32: two matmuls with triangular ones matrices
+at ``Precision.HIGHEST`` (``_suffix_sums``), the moments in one operand
+(two for the quadratic surrogate, which takes no h). On a v5e this ran a
+solve of the paper's n = p = 1200 fit 1.8x faster than log-step
+roll-and-add suffix sums on the vector unit.
 """
 from __future__ import annotations
 
@@ -47,7 +47,6 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from ..core import surrogate
-from .cox_coord import _lower_tri, _mm, _rows_below
 
 LANES = 128
 L2, L3, DX = 0, 1, 2   # rows of the per-column constants
@@ -69,6 +68,26 @@ def n_padded(n: int) -> int:
 def fits(n: int) -> bool:
     """Whether a sweep over n samples fits the kernel's VMEM budget."""
     return (2 * BLOCK_COLS + _LIVE_VECTORS) * n_padded(n) * 4 <= VMEM_BUDGET
+
+
+def _lower_tri(bs: int, dtype=jnp.float32):
+    """(P @ L)[., i] = sum_{j >= i} P[., j]  (suffix over the lane axis)."""
+    row = jax.lax.broadcasted_iota(jnp.int32, (bs, bs), 0)
+    col = jax.lax.broadcasted_iota(jnp.int32, (bs, bs), 1)
+    return (row >= col).astype(dtype)
+
+
+def _rows_below(br: int, dtype=jnp.float32):
+    """(U @ P)[r, .] = sum_{r' > r} P[r', .]  (rows strictly below)."""
+    row = jax.lax.broadcasted_iota(jnp.int32, (br, br), 0)
+    col = jax.lax.broadcasted_iota(jnp.int32, (br, br), 1)
+    return (col > row).astype(dtype)
+
+
+def _mm(a, b):
+    return jax.lax.dot_general(a, b, (((1,), (0,)), ((), ())),
+                               precision=jax.lax.Precision.HIGHEST,
+                               preferred_element_type=jnp.float32)
 
 
 def _suffix_sums(*vs):

@@ -1,20 +1,28 @@
 """jit'd public wrappers around the Pallas kernels.
 
+The kernels, and what each serves:
+  * ``cd_sweep`` (kernels/cd_sweep.py) -- one whole coordinate sweep of
+    ``solvers.fit_cd``/``fit_cd_tol`` where the program is lowered for
+    TPU. It takes tied data: it weights each suffix sum by E, the events
+    whose risk set starts there (``core.cox.risk_start_events``), so it
+    needs no gather at risk_start. It runs for float32 data whose n fits
+    its VMEM budget (``cd_sweep_path`` says which path a sweep takes, and
+    why);
+  * ``revcumsum`` -- the chunked suffix sums of the streaming fit
+    (``core/streaming.py``, ``solvers.fit_stream``);
+  * ``cox_batch_grad_hess`` -- all-coordinate (grad, hess_diag) of one
+    tie-free chunk, in the streaming fit's chunk mode;
+  * ``survival_curves`` / ``survival_curves_stratified`` -- the serving
+    engine's survival curves (``serving/engine.py``).
+
 Selection logic:
-  * on TPU the compiled kernels run natively; elsewhere (this container)
-    they run in interpret mode for correctness (the kernels resolve
+  * on TPU the compiled kernels run natively; elsewhere (the CPU) they
+    run in interpret mode for correctness (the kernels resolve
     ``interpret=None`` backend-aware themselves);
   * block sizes default to the autotuner's winners (kernels/autotune.py):
     every dispatch looks up backend + kernel + power-of-two shape bucket
     in the JSON tune cache and falls back to the historical static
-    defaults when the bucket is untuned. Pass an explicit block to pin;
-  * the coordinate-sweep kernel (kernels/cd_sweep.py) takes tied data:
-    it weights each suffix sum by E, the events whose risk set starts
-    there (``core.cox.risk_start_events``), so it needs no gather at
-    risk_start. It runs for float32 data whose n fits its VMEM budget
-    (``cd_sweep_path`` says which path a sweep takes, and why);
-  * the other Cox kernels implement the tie-free fast path, and data with
-    tied event times falls back to the pure-jnp Breslow reference there.
+    defaults when the bucket is untuned. Pass an explicit block to pin.
 
 Telemetry: every dispatch increments ``kernel_dispatch_total`` labelled
 with the kernel name and block provenance (``tuned`` cache hit /
@@ -33,11 +41,10 @@ import jax
 import jax.numpy as jnp
 
 from ..obs import metrics as obs_metrics
-from . import autotune, cd_sweep as _cd_sweep, ref
+from . import autotune, cd_sweep as _cd_sweep
 from .cd_sweep import cd_sweep  # noqa: F401  (one whole sweep, one kernel)
 from .cd_sweep import prepare as cd_sweep_prepare  # noqa: F401
 from .cox_batch import cox_batch as _cox_batch_kernel
-from .cox_coord import cox_coord as _cox_coord_kernel
 from .revcumsum import revcumsum as _revcumsum_kernel
 from .survival_curves import survival_curves as _survival_curves_kernel
 from .survival_curves import (
@@ -74,25 +81,6 @@ def revcumsum(x: jax.Array, block_n: Optional[int] = None) -> jax.Array:
         block_n = cfg["block_n"]
     out = _revcumsum_kernel(x2, block_n=block_n)
     return out[:, 0] if squeeze else out
-
-
-def cox_coord_grad_hess(eta: jax.Array, x: jax.Array, delta: jax.Array,
-                        order: int = 2, block: Optional[int] = None):
-    """Fused per-coordinate (g, h) — tie-free fast path."""
-    cfg = _blocks("cox_coord", block is not None, n=eta.shape[0])
-    if block is None:
-        block = cfg["block"]
-    g, h, _ = _cox_coord_kernel(eta, x, delta, order=order, block=block)
-    return g, h
-
-
-def cox_coord_all(eta: jax.Array, x: jax.Array, delta: jax.Array,
-                  block: Optional[int] = None):
-    """Fused per-coordinate (g, h, c3) including the third partial."""
-    cfg = _blocks("cox_coord", block is not None, n=eta.shape[0])
-    if block is None:
-        block = cfg["block"]
-    return _cox_coord_kernel(eta, x, delta, order=3, block=block)
 
 
 def cox_batch_grad_hess(eta: jax.Array, x: jax.Array, delta: jax.Array,
@@ -144,18 +132,6 @@ def survival_curves_stratified(eta: jax.Array, h0: jax.Array,
     if block_g is None:
         block_g = cfg["block_g"]
     return _survival_curves_strat_kernel(eta, h0, strata, block_g=block_g)
-
-
-def lipschitz_constants(x: jax.Array, delta: jax.Array,
-                        block_n: Optional[int] = None):
-    """(L2, L3) Theorem-3.4 constants — tie-free fast path."""
-    from .lipschitz import lipschitz as _lips_kernel
-
-    cfg = _blocks("lipschitz", block_n is not None,
-                  n=x.shape[0], m=x.shape[1])
-    if block_n is None:
-        block_n = cfg["block_n"]
-    return _lips_kernel(x, delta, block_n=block_n)
 
 
 def cd_sweep_path(x: jax.Array) -> str:

@@ -11,26 +11,6 @@ def revcumsum_ref(x: jax.Array) -> jax.Array:
                           reverse=True).astype(x.dtype)
 
 
-def cox_coord_ref(eta: jax.Array, x: jax.Array, delta: jax.Array,
-                  order: int = 2):
-    """(g, h, c3) with risk set R_i = {j >= i} (strictly increasing times)."""
-    eta = eta.astype(jnp.float32)
-    x = x.astype(jnp.float32)
-    delta = delta.astype(jnp.float32)
-    w = jnp.exp(eta - jnp.max(eta))
-    rc = lambda v: jax.lax.cumsum(v, axis=0, reverse=True)
-    s0 = rc(w)
-    m1 = rc(w * x) / s0
-    m2 = rc(w * x * x) / s0
-    g = jnp.sum(delta * (m1 - x))
-    h = jnp.sum(delta * (m2 - m1 * m1))
-    if order < 3:
-        return g, h, jnp.float32(0.0)
-    m3 = rc(w * x**3) / s0
-    c3 = jnp.sum(delta * (m3 + 2.0 * m1**3 - 3.0 * m2 * m1))
-    return g, h, c3
-
-
 def cox_batch_ref(x: jax.Array, w: jax.Array, r: jax.Array, wa: jax.Array,
                   delta: jax.Array, inv_s0: jax.Array):
     """All-coordinate (grad, hess_diag) from precomputed vectors."""
@@ -56,17 +36,3 @@ def survival_curves_stratified_ref(eta: jax.Array, h0: jax.Array,
     risk = jnp.exp(jnp.clip(eta.astype(jnp.float32), -30.0, 30.0))
     return jnp.exp(-h0.astype(jnp.float32)[strata] * risk[:, None])
 
-
-def lipschitz_ref(x: jax.Array, delta: jax.Array):
-    """(L2, L3) Theorem-3.4 constants for a time-sorted tie-free panel."""
-    import numpy as np
-
-    x = x.astype(jnp.float32)
-    smax = jax.lax.associative_scan(jnp.maximum, x[::-1], axis=0)[::-1]
-    smin = jax.lax.associative_scan(jnp.minimum, x[::-1], axis=0)[::-1]
-    rng = smax - smin
-    d = delta.astype(jnp.float32)[:, None]
-    l2 = 0.25 * jnp.sum(d * rng * rng, axis=0)
-    l3 = jnp.float32(1.0 / (6.0 * np.sqrt(3.0))) * jnp.sum(
-        d * rng * rng * rng, axis=0)
-    return l2, l3

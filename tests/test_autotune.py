@@ -116,9 +116,7 @@ def test_autotune_registers_into_roofline(tmp_path):
 
 RAGGED_SHAPES = {
     "revcumsum": {"n": 333, "m": 5},
-    "cox_coord": {"n": 517},
     "cox_batch": {"n": 261, "p": 19},
-    "lipschitz": {"n": 300, "m": 7},
     "survival_curves": {"b": 77, "g": 33},
     "survival_curves_strat": {"b": 77, "g": 33},
 }
@@ -127,12 +125,8 @@ RAGGED_SHAPES = {
 def _reference(kernel, inputs):
     if kernel == "revcumsum":
         return ref.revcumsum_ref(*inputs)
-    if kernel == "cox_coord":
-        return ref.cox_coord_ref(*inputs)
     if kernel == "cox_batch":
         return ref.cox_batch_ref(*inputs)
-    if kernel == "lipschitz":
-        return ref.lipschitz_ref(*inputs)
     if kernel == "survival_curves_strat":
         return ref.survival_curves_stratified_ref(*inputs)
     return ref.survival_curves_ref(*inputs)

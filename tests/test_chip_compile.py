@@ -21,8 +21,6 @@ from jax.sharding import SingleDeviceSharding
 from repro.core import cox, solvers
 from repro.kernels import autotune, cd_sweep
 from repro.kernels.cox_batch import cox_batch
-from repro.kernels.cox_coord import cox_coord
-from repro.kernels.lipschitz import lipschitz
 from repro.kernels.revcumsum import revcumsum
 from repro.kernels.survival_curves import (survival_curves,
                                            survival_curves_stratified)
@@ -68,10 +66,10 @@ def _compile(fn, one_chip, *shapes):
 
 
 F32, I32 = jnp.float32, jnp.int32
-N_COORD = 1 << 20
 N_SWEEP = P_SWEEP = 1200
-SWEEP_ROWS = cd_sweep.n_padded(N_SWEEP) // cd_sweep.LANES
-SWEEP_BLOCKS = -(-P_SWEEP // cd_sweep.BLOCK_COLS)
+# the largest n the sweep kernel takes (cd_sweep.fits)
+N_SWEEP_MAX = max(k * cd_sweep.TILE for k in range(1, 1024)
+                  if cd_sweep.fits(k * cd_sweep.TILE))
 
 
 def _cd_sweep(eta, beta, xt, ev, cols, lam1, lam2, cubic, interpret):
@@ -80,21 +78,21 @@ def _cd_sweep(eta, beta, xt, ev, cols, lam1, lam2, cubic, interpret):
                              interpret=interpret)
 
 
-_SWEEP_SHAPES = [((N_SWEEP,), F32), ((P_SWEEP,), F32),
-                 ((SWEEP_BLOCKS * cd_sweep.BLOCK_COLS, SWEEP_ROWS,
-                   cd_sweep.LANES), F32),
-                 ((SWEEP_ROWS, cd_sweep.LANES), F32),
-                 ((SWEEP_BLOCKS, 3, cd_sweep.BLOCK_COLS), F32),
-                 ((), F32), ((), F32)]
+def _sweep_shapes(n, p):
+    """``_cd_sweep``'s argument shapes for n samples and p columns."""
+    rows = cd_sweep.n_padded(n) // cd_sweep.LANES
+    blocks = -(-p // cd_sweep.BLOCK_COLS)
+    return [((n,), F32), ((p,), F32),
+            ((blocks * cd_sweep.BLOCK_COLS, rows, cd_sweep.LANES), F32),
+            ((rows, cd_sweep.LANES), F32),
+            ((blocks, 3, cd_sweep.BLOCK_COLS), F32),
+            ((), F32), ((), F32)]
+
+
+_SWEEP_SHAPES = _sweep_shapes(N_SWEEP, P_SWEEP)
 
 # (kernel, default-config key, fixed kwargs, argument shapes)
 CASES = {
-    "cox_coord_order2": (
-        cox_coord, "cox_coord", {"order": 2},
-        [((N_COORD,), F32)] * 3),
-    "cox_coord_order3": (
-        cox_coord, "cox_coord", {"order": 3},
-        [((N_COORD,), F32)] * 3),
     "cox_batch": (
         cox_batch, "cox_batch", {},
         [((65536, 256), F32)] + [((65536,), F32)] * 5),
@@ -102,9 +100,6 @@ CASES = {
         revcumsum, "revcumsum", {}, [((65536, 1), F32)]),
     "revcumsum_m256": (
         revcumsum, "revcumsum", {}, [((65536, 256), F32)]),
-    "lipschitz": (
-        lipschitz, "lipschitz", {},
-        [((65536, 256), F32), ((65536,), F32)]),
     "survival_curves": (
         survival_curves, "survival_curves", {},
         [((1024,), F32), ((2048,), F32)]),
@@ -113,6 +108,8 @@ CASES = {
         [((1024,), F32), ((8, 2048), F32), ((1024,), I32)]),
     "cd_sweep_quad": (_cd_sweep, None, {"cubic": False}, _SWEEP_SHAPES),
     "cd_sweep_cubic": (_cd_sweep, None, {"cubic": True}, _SWEEP_SHAPES),
+    "cd_sweep_max_n": (_cd_sweep, None, {"cubic": True},
+                       _sweep_shapes(N_SWEEP_MAX, 2 * cd_sweep.BLOCK_COLS)),
 }
 
 

@@ -39,9 +39,11 @@ def test_single_chip_phases_pass_on_cpu(chip_smoke, capsys):
               ("stream", chip_smoke.phase_stream),
               ("serve", chip_smoke.phase_serve),
               ("deep", chip_smoke.phase_deep)]
-    # on the CPU the stream resolves to the jnp scan and the engine to the
-    # jnp baseline gather; the other kernels run in interpret mode
-    expected = {"fit": ("cox_coord",), "serve": ("survival_curves",),
+    # on the CPU the stream resolves to the jnp scan, the engine to the
+    # jnp baseline gather and the fit's sweeps to the jnp sweep (traced
+    # onto the kernel path all the same); the other kernels run in
+    # interpret mode
+    expected = {"fit": ("cd_sweep",), "serve": ("survival_curves",),
                 "deep": ("survival_curves",)}
     ok = chip_smoke.run_phases(phases, tiny_sizes(chip_smoke),
                                chip_smoke.CompileClock(), expected,

@@ -7,7 +7,6 @@ import pytest
 from repro.core import cox
 from repro.kernels import ops, ref
 from repro.kernels.cox_batch import cox_batch
-from repro.kernels.cox_coord import cox_coord
 from repro.kernels.revcumsum import revcumsum
 
 
@@ -29,22 +28,6 @@ def test_revcumsum_matches_ref(n, m, dtype):
     np.testing.assert_allclose(np.asarray(out, np.float32),
                                np.asarray(expect, np.float32),
                                rtol=tol, atol=tol)
-
-
-@pytest.mark.parametrize("n", [5, 64, 257, 1024, 3000])
-@pytest.mark.parametrize("block", [128, 1024])
-@pytest.mark.parametrize("order", [2, 3])
-def test_cox_coord_matches_ref(n, block, order):
-    rng = np.random.default_rng(n + order)
-    eta = jnp.asarray(rng.standard_normal(n) * 0.8, jnp.float32)
-    x = jnp.asarray(rng.standard_normal(n), jnp.float32)
-    d = jnp.asarray((rng.uniform(size=n) < 0.7).astype(np.float32))
-    g, h, c3 = cox_coord(eta, x, d, order=order, block=block, interpret=True)
-    g_r, h_r, c3_r = ref.cox_coord_ref(eta, x, d, order=order)
-    np.testing.assert_allclose(g, g_r, rtol=2e-5, atol=2e-5)
-    np.testing.assert_allclose(h, h_r, rtol=2e-5, atol=2e-5)
-    if order >= 3:
-        np.testing.assert_allclose(c3, c3_r, rtol=2e-4, atol=2e-4)
 
 
 @pytest.mark.parametrize("n,p", [(64, 8), (500, 33), (1024, 256), (2050, 70)])
@@ -85,60 +68,11 @@ def test_ops_against_core_no_ties():
     np.testing.assert_allclose(g_all, g_core, rtol=2e-4, atol=2e-4)
     np.testing.assert_allclose(h_all, h_core, rtol=2e-4, atol=2e-4)
 
-    for l in [0, 5, 11]:
-        g, h = ops.cox_coord_grad_hess(eta, data.x[:, l], data.delta)
-        g_c, h_c, _ = cox.coord_derivs(data, eta, data.x[:, l])
-        np.testing.assert_allclose(g, g_c, rtol=2e-4, atol=2e-4)
-        np.testing.assert_allclose(h, h_c, rtol=2e-4, atol=2e-4)
-
 
 def test_revcumsum_ops_1d():
     x = _rand((777,), jnp.float32, seed=9)
     np.testing.assert_allclose(ops.revcumsum(x), ref.revcumsum_ref(x),
                                rtol=1e-5, atol=1e-5)
-
-
-def test_fit_cd_with_pallas_kernel_path():
-    """End-to-end: the fused-kernel CD (interpret mode) walks the same
-    trajectory as the jnp CD on tie-free data — the paper's solver with the
-    TPU fast path engaged."""
-    from repro.core import solvers
-
-    rng = np.random.default_rng(7)
-    n, p = 300, 10
-    x = rng.standard_normal((n, p)).astype(np.float32)
-    t = rng.uniform(1.0, 2.0, size=n).astype(np.float32)
-    assert len(np.unique(t)) == n
-    delta = (rng.uniform(size=n) < 0.6).astype(np.float32)
-    data = cox.prepare(x, t, delta)
-    for method in ("cd_quad", "cd_cubic"):
-        res_k = solvers.fit_cd(data, lam1=0.5, lam2=0.5, n_iters=8,
-                               method=method, use_kernel=True)
-        res_j = solvers.fit_cd(data, lam1=0.5, lam2=0.5, n_iters=8,
-                               method=method, use_kernel=False)
-        np.testing.assert_allclose(np.asarray(res_k.objective),
-                                   np.asarray(res_j.objective),
-                                   rtol=1e-4, atol=1e-4)
-        np.testing.assert_allclose(np.asarray(res_k.beta),
-                                   np.asarray(res_j.beta),
-                                   rtol=1e-3, atol=1e-4)
-
-
-@pytest.mark.parametrize("n,m", [(50, 4), (513, 8), (2000, 16)])
-def test_lipschitz_kernel_matches_core(n, m):
-    """Pallas Lipschitz constants == core.cox.lipschitz_constants on
-    tie-free sorted data (sweep shapes incl. non-multiple-of-block n)."""
-    rng = np.random.default_rng(n + m)
-    x = rng.standard_normal((n, m)).astype(np.float32)
-    # distinct-by-construction times (f32 uniform draws collide at n=2000)
-    t = rng.permutation(1.0 + np.arange(n) / n).astype(np.float32)
-    assert len(np.unique(t)) == n
-    delta = (rng.uniform(size=n) < 0.6).astype(np.float32)
-    data = cox.prepare(x, t, delta)
-    l2_ref, l3_ref = cox.lipschitz_constants(data)
-    l2_k, l3_k = ops.lipschitz_constants(data.x, data.delta, block_n=256)
-    np.testing.assert_allclose(l2_k, l2_ref, rtol=1e-4, atol=1e-4)
-    np.testing.assert_allclose(l3_k, l3_ref, rtol=1e-4, atol=1e-4)
 
 
 @pytest.mark.parametrize("b,s,g", [(1, 1, 16), (37, 5, 200), (64, 3, 128),
@@ -214,8 +148,7 @@ def test_cd_sweep_kernel_matches_jnp_sweep(n, p, ties, method):
     beta = jnp.asarray(rng.standard_normal(p).astype(np.float32) * 0.2)
     eta = data.x @ beta
     cubic = method == "cd_cubic"
-    want = solvers._cd_sweep_jnp(data, eta, beta, l2c, l3c, ev, 0.5, 0.5,
-                                 cubic)
+    want = solvers._cd_sweep(data, eta, beta, l2c, l3c, ev, 0.5, 0.5, cubic)
     prep = ops.cd_sweep_prepare(data.x, ev, data.delta, l2c, l3c)
     got = ops.cd_sweep(eta, beta, prep, 0.5, 0.5, cubic=cubic,
                        interpret=True)
@@ -248,6 +181,64 @@ def test_fit_cd_tol_through_sweep_kernel(monkeypatch):
                                np.asarray(want.objective), rtol=1e-6)
     np.testing.assert_allclose(np.asarray(got.beta), np.asarray(want.beta),
                                rtol=1e-4, atol=1e-5)
+
+
+@pytest.mark.parametrize("fit", ["cd_quad", "cd_cubic"])
+def test_fit_cd_through_sweep_kernel(monkeypatch, fit):
+    """The scan fit with its sweeps on the kernel (the TPU branch, run in
+    TPU interpret mode) walks the jnp path's objective trace to 1e-6."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    from repro.core import solvers
+
+    data = _sweep_cohort(200, 12, True, seed=6)
+    kw = dict(lam1=0.5, lam2=0.5, n_iters=10, method=fit)
+    want = solvers.fit_cd(data, **kw)
+    monkeypatch.setattr(jax.lax, "platform_dependent",
+                        lambda *a, tpu, default: tpu(*a))
+    run = jax.jit(lambda d: solvers.fit_cd.__wrapped__(d, **kw))
+    with pltpu.force_tpu_interpret_mode():
+        got = run(data)
+    assert int(got.n_iters) == int(want.n_iters) == 10
+    np.testing.assert_allclose(np.asarray(got.objective),
+                               np.asarray(want.objective), rtol=1e-6)
+    np.testing.assert_allclose(np.asarray(got.beta), np.asarray(want.beta),
+                               rtol=1e-4, atol=1e-5)
+
+
+@pytest.mark.parametrize("n", [5, 64, 257, 1024, 3000])
+@pytest.mark.parametrize("method", ["cd_quad", "cd_cubic"])
+@pytest.mark.parametrize("ties", [False, True])
+def test_sweep_coordinate_matches_coord_derivs(n, method, ties):
+    """A one-column sweep through the kernel (interpret mode) is one
+    coordinate visit: ``cox.coord_derivs`` at that column, the
+    surrogate's prox, and the step added to beta and to eta."""
+    from repro.core import surrogate
+
+    data = _sweep_cohort(n, 1, ties, seed=n + 11)
+    rng = np.random.default_rng(n)
+    eta = jnp.asarray(rng.standard_normal(n).astype(np.float32) * 0.8)
+    beta = jnp.asarray([0.3], jnp.float32)
+    lam1, lam2 = 0.05, 0.5
+    l2c, l3c = cox.lipschitz_constants(data)
+    ev = cox.risk_start_events(data)
+    xl = data.x[:, 0]
+    g, h, _ = cox.coord_derivs(data, eta, xl, order=2, ev=ev)
+    a = g + 2.0 * lam2 * beta[0]
+    if method == "cd_cubic":
+        step = surrogate.cubic_l1_prox(a, h + 2.0 * lam2, l3c[0], beta[0],
+                                       lam1)
+    else:
+        step = surrogate.quad_l1_prox(a, l2c[0] + 2.0 * lam2, beta[0], lam1)
+    assert float(step) != 0.0
+    prep = ops.cd_sweep_prepare(data.x, ev, data.delta, l2c, l3c)
+    got_eta, got_beta = ops.cd_sweep(eta, beta, prep, lam1, lam2,
+                                     cubic=method == "cd_cubic",
+                                     interpret=True)
+    for got, want in ((got_beta, beta + step), (got_eta, eta + step * xl)):
+        got, want = np.asarray(got), np.asarray(want)
+        np.testing.assert_allclose(got, want, rtol=2e-5,
+                                   atol=2e-5 * np.max(np.abs(want)))
 
 
 def test_cd_sweep_path_counter():

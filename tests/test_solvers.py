@@ -176,3 +176,43 @@ def test_finetune_with_and_without_hoisted_events(tied, monkeypatch):
                     static_argnames=("k_max", "n_sweeps"))
     _, _, per_coord = inner(tied, idx, mask, 1e-3, 4, n_sweeps=30)
     np.testing.assert_allclose(float(hoisted), float(per_coord), rtol=1e-12)
+
+
+def _f32_cohort(ties):
+    """n = 200, p = 12 in float32; ``ties`` puts times on a coarse grid."""
+    rng = np.random.default_rng(12)
+    x = rng.standard_normal((200, 12)).astype(np.float32)
+    t = (rng.integers(0, 40, 200) if ties
+         else rng.permutation(200)).astype(np.float32)
+    delta = (rng.uniform(size=200) < 0.6).astype(np.float32)
+    return cox.prepare(x, t, delta)
+
+
+@pytest.mark.parametrize("penalty", ["scad", "mcp"])
+@pytest.mark.parametrize("ties", [False, True])
+def test_penalized_at_zero_lam_equals_fit_cd(penalty, ties):
+    """With lam1 = 0 the SCAD / MCP prox is the quadratic surrogate's
+    Newton step, so the penalized fit walks ``fit_cd(cd_quad)``'s
+    path: the two run the one coordinate sweep."""
+    data = _f32_cohort(ties)
+    pen = solvers.fit_cd_penalized(data, penalty=penalty, lam1=0.0,
+                                   lam2=0.1, n_iters=15)
+    quad = solvers.fit_cd(data, lam1=0.0, lam2=0.1, n_iters=15,
+                          method="cd_quad")
+    np.testing.assert_allclose(np.asarray(pen.beta), np.asarray(quad.beta),
+                               rtol=0, atol=1e-6)
+    np.testing.assert_allclose(np.asarray(pen.objective),
+                               np.asarray(quad.objective), rtol=1e-6)
+
+
+@pytest.mark.parametrize("ties", [False, True])
+def test_finetune_full_support_equals_fit_cd(ties):
+    """beam.finetune over every column is the ridge-only ``fit_cd``."""
+    data = _f32_cohort(ties)
+    idx = jnp.arange(data.p, dtype=jnp.int32)
+    mask = jnp.ones(data.p, jnp.float32)
+    beta_s, _, _ = beam.finetune(data, idx, mask, 0.1, data.p, n_sweeps=20)
+    want = solvers.fit_cd(data, lam1=0.0, lam2=0.1, n_iters=20,
+                          method="cd_quad")
+    np.testing.assert_allclose(np.asarray(beta_s), np.asarray(want.beta),
+                               rtol=0, atol=1e-6)
