@@ -3,7 +3,7 @@
     python chip_smoke.py              # one chip: fit, stream, serve, deep
     python chip_smoke.py --chips 4    # four chips: sharded fit + scoring
 
-With one chip, four phases run in this one process, on seeded data:
+With one chip, five phases run in this one process, on seeded data:
 
 * ``fit``    -- ``solvers.fit_cd`` and ``beam.beam_search(k=15)`` on the
   paper's Appendix-C generator at the ``SyntheticSpec`` defaults, then
@@ -15,7 +15,12 @@ With one chip, four phases run in this one process, on seeded data:
   through ``ModelRegistry`` and answered by ``RiskService``, compared with
   a numpy reference;
 * ``deep``   -- mamba2-130m at its published widths trained for a few
-  steps under the CPH objective, refit to a sparse head and served.
+  steps under the CPH objective, refit to a sparse head and served;
+* ``ssd``    -- the chunked SSD kernel (``kernels/ssd.py``) against the jnp
+  body (``models/ssm._ssd_chunked``) on the same device, forward and
+  backward, at the shapes of the ``mamba2.train`` and ``nemotron3.train``
+  cells: the largest relative gaps of y, the final state and each
+  cotangent.
 
 With ``--chips 4`` only the paths that span chips run:
 ``distributed.fit_cd_sharded`` against ``solvers.fit_cd`` on one device,
@@ -65,6 +70,11 @@ class Sizes:
     mc_p: int = 128
     mc_sweeps: int = 2
     mc_batch: int = 1 << 16
+    ssd_batch: int = 32
+    ssd_seq: int = 512
+    # (heads, head width, groups, state size, chunk) of the SSD in the
+    # mamba2.train and the nemotron3.train cells
+    ssd_shapes: tuple = ((24, 64, 1, 128, 256), (64, 64, 8, 128, 128))
 
 
 # request batch sizes submitted back to back; the service cuts them into
@@ -367,6 +377,62 @@ def phase_deep(sz: Sizes, state: dict, cfg=None) -> dict:
             "refit_rows": len(feats), "served": served}
 
 
+def ssd_inputs(key, b: int, s: int, h: int, hd: int, g: int, n: int):
+    """Seeded SSD arguments (x, dt, A, B, C) as the Mamba-2 block makes
+    them, and the weights (of y and of the final state) of a scalar."""
+    import jax
+    import jax.numpy as jnp
+
+    k = jax.random.split(key, 6)
+    normal = lambda i, shape: jax.random.normal(k[i], shape,  # noqa: E731
+                                                jnp.float32)
+    return ((normal(0, (b, s, h, hd)),
+             jax.nn.softplus(normal(1, (b, s, h)) - 1.0),
+             -jnp.linspace(1.0, 16.0, h, dtype=jnp.float32),
+             normal(2, (b, s, g, n)), normal(3, (b, s, g, n))),
+            normal(4, (b, s, h, hd)), normal(5, (b, h, hd, n)))
+
+
+def phase_ssd(sz: Sizes, state: dict) -> dict:
+    """The SSD kernel against the jnp body, both on the default device."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from repro.kernels import ops
+    from repro.models import ssm
+
+    out = {}
+    for i, (h, hd, g, n, q) in enumerate(sz.ssd_shapes):
+        args, wy, ws = ssd_inputs(jax.random.PRNGKey(i), sz.ssd_batch,
+                                  sz.ssd_seq, h, hd, g, n)
+        bodies = {"kernel": lambda *a: ops.ssd(*a, q),
+                  "jnp": lambda *a: ssm._ssd_chunked(*a, q)}
+        res, times = {}, {}
+        for name, body in bodies.items():
+            def scalar(*a, body=body):
+                y, st = body(*a)
+                return jnp.sum(y * wy) + jnp.sum(st * ws)
+
+            run = jax.jit(lambda *a, body=body, scalar=scalar: (
+                body(*a), jax.grad(scalar, argnums=range(5))(*a)))
+            (y, st), grads = jax.block_until_ready(run(*args))
+            t0 = time.perf_counter()
+            for _ in range(3):
+                jax.block_until_ready(run(*args))
+            times[name] = (time.perf_counter() - t0) / 3 * 1e3
+            res[name] = [np.asarray(v) for v in (y, st, *grads)]
+        gaps = {}
+        for key, got, want in zip(("y", "state", "dx", "ddt", "dA", "dB",
+                                   "dC"), res["kernel"], res["jnp"]):
+            check(bool(np.isfinite(got).all()), f"kernel {key} not finite")
+            gaps[key] = float(np.max(np.abs(got - want))
+                              / max(float(np.max(np.abs(want))), 1e-30))
+        out[f"H{h}_hd{hd}_G{g}_N{n}_Q{q}"] = {
+            "max_rel_gap": gaps, "fwd_bwd_ms": times}
+    return {"batch": sz.ssd_batch, "seq": sz.ssd_seq, **out}
+
+
 def phase_multichip(sz: Sizes, state: dict) -> dict:
     """Sharded exact fit and data-parallel scoring across every device."""
     import jax
@@ -494,7 +560,8 @@ def main(argv=None) -> int:
         phases = [("multichip", phase_multichip)]
     else:
         phases = [("fit", phase_fit), ("stream", phase_stream),
-                  ("serve", phase_serve), ("deep", phase_deep)]
+                  ("serve", phase_serve), ("deep", phase_deep),
+                  ("ssd", phase_ssd)]
     if not run_phases(phases, Sizes(), clock):
         return 1
     print(json.dumps({"ok": True, "device": {

@@ -8,6 +8,9 @@ The kernels, and what each serves:
     needs no gather at risk_start. It runs for float32 data whose n fits
     its VMEM budget (``cd_sweep_path`` says which path a sweep takes, and
     why);
+  * ``ssd`` (kernels/ssd.py) -- the chunked SSD of the Mamba-2 block
+    (``models/ssm.mamba2_forward``), forward and backward, where the
+    program is lowered for TPU and the shapes tile (``ssd_path``);
   * ``revcumsum`` -- the chunked suffix sums of the streaming fit
     (``core/streaming.py``, ``solvers.fit_stream``);
   * ``cox_batch_grad_hess`` -- all-coordinate (grad, hess_diag) of one
@@ -31,7 +34,10 @@ dispatch-side: a kernel traced once inside an outer ``jit`` counts once
 per compilation, eager callers count per call — either way, a fleet
 silently running default blocks is visible in the metrics.
 ``cd_sweep_path_total`` counts, the same way, which path each traced
-coordinate sweep took (``kernel`` or ``jnp``) and why.
+coordinate sweep took (``kernel`` or ``jnp``) and why. ``ssd_path_total``
+counts which path each chunked SSD took: a shape that does not tile when
+it is traced, the platform's choice when the program is lowered (so a
+rematerialised forward counts again).
 """
 from __future__ import annotations
 
@@ -39,12 +45,15 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax.extend.core import Primitive
+from jax.interpreters import ad, batching, mlir
 
 from ..obs import metrics as obs_metrics
-from . import autotune, cd_sweep as _cd_sweep
+from . import autotune, cd_sweep as _cd_sweep, ssd as _ssd
 from .cd_sweep import cd_sweep  # noqa: F401  (one whole sweep, one kernel)
 from .cd_sweep import prepare as cd_sweep_prepare  # noqa: F401
 from .cox_batch import cox_batch as _cox_batch_kernel
+from .ssd import ssd  # noqa: F401  (the chunked SSD, forward and backward)
 from .revcumsum import revcumsum as _revcumsum_kernel
 from .survival_curves import survival_curves as _survival_curves_kernel
 from .survival_curves import (
@@ -58,6 +67,13 @@ _M_SWEEP = obs_metrics.REGISTRY.counter(
     "cd_sweep_path_total",
     "coordinate sweeps traced, by path: kernel (the one-kernel sweep, run "
     "where the program is lowered for TPU) or jnp, and the reason",
+    ("path", "reason"))
+
+_M_SSD = obs_metrics.REGISTRY.counter(
+    "ssd_path_total",
+    "chunked SSDs by path: kernel (kernels/ssd.py, where the program is "
+    "lowered for TPU and the shapes tile) or jnp, and the reason: fits, "
+    "backend or shape",
     ("path", "reason"))
 
 
@@ -149,3 +165,41 @@ def cd_sweep_path(x: jax.Array) -> str:
     _M_SWEEP.inc(path=path, reason=reason)
     return path
 
+
+def ssd_path(h: int, hd: int, g: int, n: int, chunk: int) -> str:
+    """``"kernel"`` where the SSD kernel takes H heads of width hd over
+    G groups of state size N in chunks of ``chunk``, else ``"jnp"``,
+    counted now under ``ssd_path_total`` with the reason ``shape``. Where
+    the shapes tile, the platform decides when the program is lowered:
+    ``ssd_lowered`` counts that."""
+    if _ssd.tiles(h, hd, g, n, chunk):
+        return "kernel"
+    _M_SSD.inc(path="jnp", reason="shape")
+    return "jnp"
+
+
+# identity on one array; lowering it counts the path of the SSD it marks
+_SSD_LOWERED = Primitive("ssd_lowered")
+_SSD_LOWERED.def_impl(lambda v, **_: v)
+_SSD_LOWERED.def_abstract_eval(lambda v, **_: v)
+ad.primitive_jvps[_SSD_LOWERED] = \
+    lambda primals, tangents, **kw: (_SSD_LOWERED.bind(*primals, **kw),
+                                     tangents[0])
+batching.primitive_batchers[_SSD_LOWERED] = \
+    lambda args, dims, **kw: (_SSD_LOWERED.bind(*args, **kw), dims[0])
+
+
+def _count_lowered(ctx, v, *, path, reason):
+    _M_SSD.inc(path=path, reason=reason)
+    return [v]
+
+
+mlir.register_lowering(_SSD_LOWERED, _count_lowered)
+
+
+def ssd_lowered(v: jax.Array, path: str, reason: str) -> jax.Array:
+    """``v`` itself; each lowering of the program counts one SSD under
+    ``ssd_path_total{path, reason}``. Put inside one branch of a
+    ``lax.platform_dependent``, it counts only where that branch is
+    lowered."""
+    return _SSD_LOWERED.bind(v, path=path, reason=reason)

@@ -15,6 +15,8 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 
+from ..kernels import ops as kops
+
 Array = jax.Array
 
 
@@ -108,7 +110,7 @@ def mamba2_forward(params, x: Array, *, d_state: int, head_dim: int = 64,
         bb = bb.reshape(b, s, n_groups, d_state)
         cc = cc.reshape(b, s, n_groups, d_state)
 
-        y, st = _ssd_chunked(xh, dt, a, bb, cc, chunk)
+        y, st = _ssd(xh, dt, a, bb, cc, chunk)
         y = y + params["d_skip"][None, None, :, None] \
             * xh.astype(jnp.float32)
         y = y.reshape(b, s, d_inner).astype(x.dtype)
@@ -124,6 +126,28 @@ def mamba2_forward(params, x: Array, *, d_state: int, head_dim: int = 64,
                 ((0, 0), (max(0, 3 - s), 0), (0, 0)))[:, -3:, :]
         return out, SSMState(conv=conv_tail, ssm=st)
     return out
+
+
+def _ssd(xh, dt, a, bb, cc, chunk):
+    """The chunked SSD, lowered per platform where the kernel takes the
+    shapes (``kernels.ops.ssd_path``): on TPU one Pallas kernel forward
+    and one backward (``kernels/ssd.py``), elsewhere ``_ssd_chunked``.
+    Each lowering is counted under ``ssd_path_total``."""
+    _, _, h, hd = xh.shape
+    g, n = bb.shape[2], bb.shape[3]
+    if kops.ssd_path(h, hd, g, n, chunk) != "kernel":
+        return _ssd_chunked(xh, dt, a, bb, cc, chunk)
+
+    def kernel(xh, dt, a, bb, cc):
+        dt = kops.ssd_lowered(dt, "kernel", "fits")
+        return kops.ssd(xh, dt, a, bb, cc, chunk, interpret=False)
+
+    def jnp_body(xh, dt, a, bb, cc):
+        dt = kops.ssd_lowered(dt, "jnp", "backend")
+        return _ssd_chunked(xh, dt, a, bb, cc, chunk)
+
+    return jax.lax.platform_dependent(xh, dt, a, bb, cc, tpu=kernel,
+                                      default=jnp_body)
 
 
 def _ssd_chunked(xh, dt, a, bb, cc, chunk):

@@ -19,7 +19,7 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro.core import cox, solvers
-from repro.kernels import autotune, cd_sweep
+from repro.kernels import autotune, cd_sweep, ssd
 from repro.kernels.cox_batch import cox_batch
 from repro.kernels.revcumsum import revcumsum
 from repro.kernels.survival_curves import (survival_curves,
@@ -91,6 +91,31 @@ def _sweep_shapes(n, p):
 
 _SWEEP_SHAPES = _sweep_shapes(N_SWEEP, P_SWEEP)
 
+
+def _ssd_fwd(x, dt, a, b, c, chunk, interpret):
+    return ssd.ssd(x, dt, a, b, c, chunk, interpret=interpret)
+
+
+def _ssd_bwd(x, dt, a, b, c, chunk, interpret):
+    """The SSD's cotangents (its forward kernel, residuals kept, then its
+    backward kernel) of a scalar of y and of the final state."""
+    def scalar(*args):
+        y, st = ssd.ssd(*args, chunk, interpret=interpret)
+        return jnp.sum(y) + jnp.sum(st)
+
+    return jax.grad(scalar, argnums=range(5))(x, dt, a, b, c)
+
+
+def _ssd_shapes(b, s, h, hd, g, n):
+    return [((b, s, h, hd), F32), ((b, s, h), F32), ((h,), F32),
+            ((b, s, g, n), F32), ((b, s, g, n), F32)]
+
+
+# the SSD of mamba2.train (chunk 256, one group of 24 heads) and of
+# nemotron3.train (chunk 128, 8 groups of 8 heads), 32 x 512 tokens
+_SSD_MAMBA2 = _ssd_shapes(32, 512, 24, 64, 1, 128)
+_SSD_NEMOTRON3 = _ssd_shapes(32, 512, 64, 64, 8, 128)
+
 # (kernel, default-config key, fixed kwargs, argument shapes)
 CASES = {
     "cox_batch": (
@@ -110,6 +135,10 @@ CASES = {
     "cd_sweep_cubic": (_cd_sweep, None, {"cubic": True}, _SWEEP_SHAPES),
     "cd_sweep_max_n": (_cd_sweep, None, {"cubic": True},
                        _sweep_shapes(N_SWEEP_MAX, 2 * cd_sweep.BLOCK_COLS)),
+    "ssd_fwd_mamba2": (_ssd_fwd, None, {"chunk": 256}, _SSD_MAMBA2),
+    "ssd_bwd_mamba2": (_ssd_bwd, None, {"chunk": 256}, _SSD_MAMBA2),
+    "ssd_fwd_nemotron3": (_ssd_fwd, None, {"chunk": 128}, _SSD_NEMOTRON3),
+    "ssd_bwd_nemotron3": (_ssd_bwd, None, {"chunk": 128}, _SSD_NEMOTRON3),
 }
 
 
@@ -208,7 +237,38 @@ def test_nemotron_train_step_fits_one_v5e(one_chip, chip_config):
     text = compiled.as_text()
     for scope in ("moe.experts/", "moe.dispatch/", "attn.core/", "ssm.ssd/"):
         assert scope in text, scope
+    # the SSD runs as its kernels, forward, recomputed forward and
+    # backward, under ssm.ssd, and no (B, nc, Q, Q, ...) tile reaches HBM
+    calls = _op_names(text, r"custom_call_target=\"tpu_custom_call\"")
+    for kernel in ("/ssm.ssd/", "/ssd_fwd/", "/ssd_bwd/"):
+        assert any(kernel in c for c in calls), (kernel, calls)
+    assert not re.search(r"\[32,4,128,128\b", text)
     mem = compiled.memory_analysis()
     need = (mem.argument_size_in_bytes + mem.output_size_in_bytes
             - mem.alias_size_in_bytes + mem.temp_size_in_bytes)
     assert need < 16e9, need
+
+
+def test_ssd_path_counter(one_chip, chip_config):
+    """``ssd_path_total`` counts the kernel where the Mamba-2 block's SSD
+    is lowered for TPU at a shape that tiles, and the jnp body with its
+    reason where it is lowered for the CPU (``backend``) or its head
+    width does not pack into lanes (``shape``)."""
+    from repro.models import ssm
+    from repro.obs import metrics
+
+    counter = metrics.REGISTRY.counter("ssd_path_total",
+                                       label_names=("path", "reason"))
+    labels = [("kernel", "fits"), ("jnp", "backend"), ("jnp", "shape")]
+
+    def lowered(hd, sharding):
+        args = [jax.ShapeDtypeStruct(s, dt, sharding=sharding)
+                for s, dt in _ssd_shapes(2, 256, 4, hd, 1, 128)]
+        before = [counter.value(path=p, reason=r) for p, r in labels]
+        jax.jit(lambda *a: ssm._ssd(*a, 128)).lower(*args)
+        return [counter.value(path=p, reason=r) - v
+                for (p, r), v in zip(labels, before)]
+
+    assert lowered(64, one_chip) == [1, 0, 0]
+    assert lowered(64, None) == [0, 1, 0]
+    assert lowered(48, one_chip) == [0, 0, 1]

@@ -28,7 +28,9 @@ def tiny_sizes(mod):
                      kernel_iters=2, stream_n=3000, stream_p=8,
                      stream_chunk=1024, requests=40, strata=3, grid=16,
                      deep_steps=2, deep_batch=8, deep_seq=16, deep_k=2,
-                     mc_n=4096, mc_p=8, mc_batch=512)
+                     mc_n=4096, mc_p=8, mc_batch=512, ssd_batch=1,
+                     ssd_seq=40, ssd_shapes=((4, 64, 1, 128, 16),
+                                             (4, 64, 2, 128, 16)))
 
 
 def test_single_chip_phases_pass_on_cpu(chip_smoke, capsys):
@@ -38,7 +40,8 @@ def test_single_chip_phases_pass_on_cpu(chip_smoke, capsys):
     phases = [("fit", chip_smoke.phase_fit),
               ("stream", chip_smoke.phase_stream),
               ("serve", chip_smoke.phase_serve),
-              ("deep", chip_smoke.phase_deep)]
+              ("deep", chip_smoke.phase_deep),
+              ("ssd", chip_smoke.phase_ssd)]
     # on the CPU the stream resolves to the jnp scan, the engine to the
     # jnp baseline gather and the fit's sweeps to the jnp sweep (traced
     # onto the kernel path all the same); the other kernels run in
@@ -58,6 +61,11 @@ def test_single_chip_phases_pass_on_cpu(chip_smoke, capsys):
     for key in ("single_stratum", "strata_3"):
         assert serve[key]["requests"] == 40
         assert serve[key]["errors"] == serve[key]["engine_failures"] == 0
+    # the SSD kernel (interpret mode here) against the jnp body
+    ssd = lines[4]
+    gaps = [g for key in ("H4_hd64_G1_N128_Q16", "H4_hd64_G2_N128_Q16")
+            for g in ssd[key]["max_rel_gap"].values()]
+    assert len(gaps) == 14 and max(gaps) < 2.5e-2, ssd
 
 
 def test_refuses_to_run_without_tpu(chip_smoke, capsys, monkeypatch):
